@@ -2,9 +2,9 @@
 """Diff bench trajectory files into a per-leg delta table with a
 regression gate.
 
-    python scripts/bench_compare.py BENCH_r05.json BENCH_r06.json
+    python scripts/bench_compare.py BENCH_r02.json BENCH_r03.json
     python scripts/bench_compare.py BENCH_r*.json            # trajectory
-    python scripts/bench_compare.py BENCH_r05.json /tmp/leg.json \
+    python scripts/bench_compare.py BENCH_r03.json /tmp/leg.json \
         --as-leg smoke --threshold 0.25
 
 With exactly two inputs, prints old-vs-new per-leg rates and exits

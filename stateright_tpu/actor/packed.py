@@ -1488,7 +1488,12 @@ class PackedActorModel(ActorModel, BatchableModel):
                 emsg = jnp.zeros((K, W), jnp.uint32)
                 eold = jnp.zeros((K,), jnp.uint32)
                 enew = jnp.zeros((K,), jnp.uint32)
-                eused = jnp.zeros((K,), bool)
+                # 0/1 in uint32, not bool: with a bool flag the sends
+                # vanished from the digest on the TPU (13,634 of 32,970
+                # paxos 2c/3s candidate fingerprints wrong; none with
+                # uint32). A minimal vmapped bool scatter does not show
+                # it, so the cause is open (ROADMAP A5).
+                eused = jnp.zeros((K,), jnp.uint32)
                 empties = (state["net_cnt"] == 0).sum(dtype=jnp.int32)
                 if do_consume:
                     c0 = state["net_cnt"][cons_slot]
@@ -1497,7 +1502,7 @@ class PackedActorModel(ActorModel, BatchableModel):
                     emsg = emsg.at[0].set(state["net_msg"][cons_slot])
                     eold = eold.at[0].set(c0)
                     enew = enew.at[0].set(c0 - 1)
-                    eused = eused.at[0].set(True)
+                    eused = eused.at[0].set(jnp.uint32(1))
                     empties = empties + (c0 == 1)
                 ov = jnp.bool_(False)
                 for si in range(S):
@@ -1506,7 +1511,7 @@ class PackedActorModel(ActorModel, BatchableModel):
                     active = dst != SN
                     src_u = src.astype(jnp.uint32)
                     wmatch = (
-                        eused
+                        (eused != 0)
                         & (esrc == src_u)
                         & (edst == dst)
                         & (emsg == msg[None, :]).all(axis=1)
@@ -1534,7 +1539,7 @@ class PackedActorModel(ActorModel, BatchableModel):
                     ok = active & (exists | has_empty)
                     ncnt = cur + jnp.where(ok, add, jnp.uint32(0))
                     claim = ok & ~exists
-                    j = jnp.where(wfound, wj, jnp.argmax(~eused))
+                    j = jnp.where(wfound, wj, jnp.argmax(eused == 0))
                     touch = ok
                     esrc = esrc.at[j].set(jnp.where(touch, src_u, esrc[j]))
                     edst = edst.at[j].set(jnp.where(touch, dst, edst[j]))
@@ -1543,7 +1548,7 @@ class PackedActorModel(ActorModel, BatchableModel):
                         jnp.where(touch & ~wfound, old0, eold[j])
                     )
                     enew = enew.at[j].set(jnp.where(touch, ncnt, enew[j]))
-                    eused = eused.at[j].set(eused[j] | touch)
+                    eused = eused.at[j].set(eused[j] | touch.astype(jnp.uint32))
                     empties = empties - claim
                     ov = ov | (active & ~exists & ~has_empty)
                 for j in range(K):
@@ -1554,7 +1559,7 @@ class PackedActorModel(ActorModel, BatchableModel):
                         emsg[j],
                         eold[j],
                         enew[j],
-                        eused[j],
+                        eused[j] != 0,
                     )
                 return digest, ov
 

@@ -7,9 +7,7 @@ Feeds both paths identical sorted batches at the checkers' target load
 factor and prints keys/sec for each. Decides whether runs should pass
 ``hashset_impl="pallas"`` to the TPU checkers (``checker/tpu.py`` — the
 default stays "xla" until the Pallas path measures faster on hardware).
-``--json`` prints ONE machine-readable line instead (recorded in
-DEVICE_RUNS.jsonl by scripts/device_bench_run.sh so the per-backend
-winner is part of the round's bench evidence).
+``--json`` prints ONE machine-readable line instead.
 """
 
 from __future__ import annotations

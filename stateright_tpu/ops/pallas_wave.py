@@ -30,8 +30,8 @@ DMA; the wave-wide compute rides the first and last steps:
   window was prefetched *before* tile ``t-1``'s claims were written
   back, and the two windows overlap by exactly the apron), start the
   prefetch of tile ``t+1`` into the opposite parity buffer, then
-  probe/claim this tile's keys in VMEM (``pallas_hashset.probe_claim``
-  — the identical claim semantics as the staged insert) and write the
+  probe/claim this tile's keys in VMEM (``probe_claim``
+  — the same claim semantics as the staged insert) and write the
   window back;
 - **epilogue** (last step): prefix-compact the fresh lanes, evaluate
   properties, reduce coverage, and emit the consolidated stats vector.
@@ -40,9 +40,10 @@ The output dict is bit-identical to the staged wave's — same sort, same
 first-occurrence dedup winner, same claim order, same compaction — so
 every consumer (deep drain, checkpointing, tiered store, AOT cache)
 composes unchanged. ``interpret=True`` (forced off-TPU) runs the real
-kernel logic on CPU for tier-1/CI; the in-kernel ``lax.sort``/gathers do
-not yet have a Mosaic lowering, so compiled-TPU support is gated on the
-interpret flag (see README "Fused wave megakernel").
+kernel logic on CPU for tier-1/CI. On a TPU the kernel is compiled and
+Mosaic refuses it: the in-kernel ``lax.sort``/gathers have no lowering
+yet ("Shape mismatch in input, indices and output";
+``tests/test_chip_compile.py`` pins it, README "Fused wave megakernel").
 """
 
 from __future__ import annotations
@@ -55,7 +56,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .hashset import MAX_PROBES
-from .pallas_hashset import _KC, TILE_ROWS, _compiler_params, probe_claim
+from .pallas_hashset import TILE_ROWS
 
 __all__ = ["FusedWaveSpec", "fused_wave"]
 
@@ -64,6 +65,37 @@ _U32_MAX = np.uint32(0xFFFFFFFF)
 # One table window: the tile plus the probe apron reaching into the next
 # tile (open addressing probes at most MAX_PROBES rows past the home).
 _WIN = TILE_ROWS + MAX_PROBES
+# Keys resolved per inner chunk.
+_KC = 256
+
+
+def probe_claim(window, kh, kl, local):
+    """Resolve one key against a VMEM table window: compare its
+    ``MAX_PROBES``-row probe window at ``local``, claim the first empty
+    slot when no match precedes it, and return ``(can_claim,
+    is_found)``. Sequential per-key use makes the claim race-free — the
+    next key observes this write in VMEM immediately, which is exact
+    CAS-free open addressing. The claim is a masked whole-probe-window
+    rewrite (a vector store — Mosaic handles dynamic scalar stores to
+    VMEM poorly)."""
+    from jax.experimental import pallas as pl
+
+    rows_hi = window[pl.ds(local, MAX_PROBES), 0]
+    rows_lo = window[pl.ds(local, MAX_PROBES), 1]
+    idx = jax.lax.broadcasted_iota(
+        jnp.int32, (MAX_PROBES, 1), 0
+    ).reshape(MAX_PROBES)
+    big = jnp.int32(MAX_PROBES)
+    empty = (rows_hi == 0) & (rows_lo == 0)
+    match = (rows_hi == kh) & (rows_lo == kl)
+    first_empty = jnp.min(jnp.where(empty, idx, big))
+    first_match = jnp.min(jnp.where(match, idx, big))
+    is_found = first_match < first_empty
+    can_claim = (first_empty < big) & ~is_found
+    claim = can_claim & (idx == first_empty)
+    window[pl.ds(local, MAX_PROBES), 0] = jnp.where(claim, kh, rows_hi)
+    window[pl.ds(local, MAX_PROBES), 1] = jnp.where(claim, kl, rows_lo)
+    return can_claim, is_found
 
 
 @dataclasses.dataclass(frozen=True, eq=False)
@@ -575,7 +607,7 @@ def fused_wave(spec: FusedWaveSpec, table, states, hi, lo, ebits, depth,
         out_shape=tuple(out_shape),
         # Table operand index counts the scalar-prefetch arg.
         input_output_aliases={1 + n_state + 5 + n_const: 0},
-        compiler_params=_compiler_params(pltpu),
+        compiler_params=pltpu.CompilerParams(has_side_effects=True),
         interpret=spec.interpret,
     )(
         dcap.reshape((1,)),

@@ -45,17 +45,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:  # jax >= 0.6: top-level export, replication check named check_vma
-    from jax import shard_map
-except ImportError:  # jax 0.4.x: experimental module, check_rep instead
-    from jax.experimental.shard_map import shard_map as _shard_map_compat
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map_compat(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check_vma,
-        )
-
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.batch import BatchableModel
@@ -445,7 +434,7 @@ class ShardedTpuBfsChecker(Checker):
             deep_in = deep_in + (P("fp"), P("fp"))
             deep_donate = (0, 1, 7, 8)
         self._jit_wave = jax.jit(
-            shard_map(
+            jax.shard_map(
                 self._wave_local,
                 mesh=self._mesh,
                 in_specs=wave_in,
@@ -465,7 +454,7 @@ class ShardedTpuBfsChecker(Checker):
         self._aot_store_arg = aot_store
         self._aot_disk = None
         self._jit_insert = jax.jit(
-            shard_map(
+            jax.shard_map(
                 self._insert_local,
                 mesh=self._mesh,
                 in_specs=(P("fp"),) * 4,
@@ -477,7 +466,7 @@ class ShardedTpuBfsChecker(Checker):
         # Only the destination table (arg 1) can alias the output; the
         # old, smaller table is freed by the caller's rebind.
         self._jit_rehash = jax.jit(
-            shard_map(
+            jax.shard_map(
                 self._rehash_local,
                 mesh=self._mesh,
                 in_specs=(P("fp"), P("fp")),
@@ -487,7 +476,7 @@ class ShardedTpuBfsChecker(Checker):
             donate_argnums=(1,),
         )
         self._jit_deep_drain = jax.jit(
-            shard_map(
+            jax.shard_map(
                 self._deep_drain_local,
                 mesh=self._mesh,
                 in_specs=deep_in,
@@ -497,7 +486,7 @@ class ShardedTpuBfsChecker(Checker):
             donate_argnums=deep_donate,
         )
         self._jit_ring_push = jax.jit(
-            shard_map(
+            jax.shard_map(
                 self._push_local,
                 mesh=self._mesh,
                 in_specs=(P("fp"),) * 4,
@@ -507,7 +496,7 @@ class ShardedTpuBfsChecker(Checker):
             donate_argnums=(0,),
         )
         self._jit_ring_export = jax.jit(
-            shard_map(
+            jax.shard_map(
                 self._ring_export_local,
                 mesh=self._mesh,
                 in_specs=(P("fp"),) * 3,
@@ -1465,6 +1454,13 @@ class ShardedTpuBfsChecker(Checker):
                 return self._evict_shards(table, defer=defer_evict)
         return out["table"]
 
+    def _record_placement(self, table):
+        """Where the visited set's shards sit at run end, read from
+        ``addressable_shards``: one ``(device, shard shape)`` per shard."""
+        self.visited_placement = [
+            (s.device, s.data.shape) for s in table.addressable_shards
+        ]
+
     def _audit_table(self, table):
         """Run-end probe-length audit over every shard's table (summed —
         the shards share one hash scheme, so one distribution describes
@@ -1726,6 +1722,7 @@ class ShardedTpuBfsChecker(Checker):
         # dominated by XLA compilation; benchmarks subtract it to report
         # steady-state rates (parity with TpuBfsChecker.warmup_seconds).
         self.warmup_seconds: Optional[float] = None
+        self.visited_placement = None
         self._pool = deque()
         self._pool_count = 0
         if self._resume_from is not None:
@@ -1891,6 +1888,7 @@ class ShardedTpuBfsChecker(Checker):
             # Run-end epoch barrier: counters and the parent-fp log must
             # be settled before the audit and the done flag.
             pipe.drain()
+        self._record_placement(table)
         self._audit_table(table)
 
     def _apply_wave_stats(self, wave, chunk=None):
@@ -2516,6 +2514,7 @@ class ShardedTpuBfsChecker(Checker):
                         res, dstats, table, pool, head, count, ring_est,
                         depth_cap, span=sp,
                     )
+        self._record_placement(table)
         self._audit_table(table)
 
     def _consume_final(

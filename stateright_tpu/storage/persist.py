@@ -9,7 +9,8 @@ discipline — **refuse, never mis-execute**:
   keyed by the checker's full AOT trace signature (the exact tuple that
   keys the in-memory ``shared_aot_cache``) plus the per-dispatch shape
   key, and every artifact carries a *fence* — jax version, backend,
-  device kind/count — verified on load. A fence mismatch or a torn/
+  device kind/count, and the ids of the devices it was compiled for,
+  which it is loaded onto — verified on load. A fence mismatch or a torn/
   corrupt pickle is counted (``aot_cache.refused_stale`` /
   ``aot_cache.refused_corrupt``) and treated as a miss: the caller
   recompiles, it never runs a stale binary. Unlike jax's own persistent
@@ -160,13 +161,18 @@ class AotDiskStore:
                 return None, "corrupt"
         except Exception:
             return None, "corrupt"
-        if entry.get("fence") != self.fence():
+        fence = dict(entry.get("fence") or {})
+        device_ids = fence.pop("execution_devices", None)
+        if fence != self.fence() or not device_ids:
             return None, "stale"
         try:
+            import jax
             from jax.experimental import serialize_executable
 
+            by_id = {d.id: d for d in jax.devices()}
             exe = serialize_executable.deserialize_and_load(
-                entry["payload"], entry["in_tree"], entry["out_tree"]
+                entry["payload"], entry["in_tree"], entry["out_tree"],
+                execution_devices=[by_id[i] for i in device_ids],
             )
         except Exception:
             # Same-fence deserialization failure: an XLA build drift the
@@ -188,13 +194,19 @@ class AotDiskStore:
             from jax.experimental import serialize_executable
 
             payload, in_tree, out_tree = serialize_executable.serialize(exe)
+            # The devices the executable was compiled for: loaded onto any
+            # other set (by default, every local device), it refuses to run.
+            devices = exe.runtime_executable().local_devices()
             serialize_executable.deserialize_and_load(
-                payload, in_tree, out_tree
+                payload, in_tree, out_tree, execution_devices=devices
             )
             blob = pickle.dumps(
                 {
                     "format": AOT_FORMAT,
-                    "fence": self.fence(),
+                    "fence": {
+                        **self.fence(),
+                        "execution_devices": [d.id for d in devices],
+                    },
                     "namespace": namespace,
                     "kind": kind,
                     "payload": payload,

@@ -476,7 +476,7 @@ class StallWatchdog:
 
     def _profiler_capture(self) -> None:
         """Best effort: profile the stalled process for ``capture_s`` so
-        the trace shows WHERE it is wedged (device tunnel, host probe,
+        the trace shows WHERE it is wedged (device call, host probe,
         compile). No-op when jax is unavailable."""
         try:
             import jax

@@ -54,8 +54,8 @@ def test_load_rates_from_wrapper_parsed(tmp_path):
 
 def test_load_rates_salvages_truncated_tail(tmp_path):
     """A killed bench tears the tail mid-line; every complete key it
-    still carries must be salvaged (BENCH_r04/r05 really look like this:
-    parsed=null, 2000-char tail starting mid-JSON)."""
+    still carries must be salvaged (a killed run's record keeps
+    parsed=null and a 2000-char tail starting mid-JSON)."""
     text = json.dumps(_bench_line(9000.0, paxos=5000.0, scr4=8863.0))
     record = {"n": 5, "rc": 0, "parsed": None,
               "tail": text[len(text) // 2:]}  # torn: keeps the late keys
@@ -152,12 +152,12 @@ def test_trajectory_table_over_three_files(tmp_path):
 
 
 def test_real_trajectory_files_compare():
-    """The committed BENCH_r04 vs r05 (both torn-tail shapes) must load
-    and diff without a gate breach at a loose threshold — the CPU-cheap
-    verify-recipe invocation."""
+    """The committed BENCH_r02 vs r03 (wrapper records with stdout
+    tails) must load and diff without a gate breach at a loose
+    threshold — the CPU-cheap verify-recipe invocation."""
     r = _run(
-        os.path.join(REPO_DIR, "BENCH_r04.json"),
-        os.path.join(REPO_DIR, "BENCH_r05.json"),
+        os.path.join(REPO_DIR, "BENCH_r02.json"),
+        os.path.join(REPO_DIR, "BENCH_r03.json"),
         "--threshold", "0.9",
     )
     assert r.returncode == 0, r.stderr + r.stdout

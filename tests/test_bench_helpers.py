@@ -1,62 +1,8 @@
-"""bench.py's evidence readers: the probe-log summary and the sentinel
-device-results collector that land in the round's bench JSON."""
+"""bench.py's pure helpers."""
 
 import json
 
 import bench  # repo root is on sys.path via tests/conftest.py
-
-
-def test_probe_log_summary(tmp_path, monkeypatch):
-    log = tmp_path / "PROBE_LOG.jsonl"
-    log.write_text(
-        '{"ts": "t1", "ok": false}\n'
-        "not json\n"
-        '{"ts": "t2", "ok": true}\n'
-        '{"ts": "t3", "ok": false, "standdown": true}\n'
-    )
-    monkeypatch.setattr(bench, "REPO_DIR", str(tmp_path))
-    s = bench._probe_log_summary()
-    assert s == {
-        "attempts": 2,
-        "ok": 1,
-        "standdowns": 1,
-        "first": "t1",
-        "last": "t3",
-        "last_ok": "t2",
-    }
-
-
-def test_probe_log_summary_absent(tmp_path, monkeypatch):
-    monkeypatch.setattr(bench, "REPO_DIR", str(tmp_path))
-    assert bench._probe_log_summary() is None
-
-
-def test_sentinel_device_results_collects_every_record_shape(
-    tmp_path, monkeypatch
-):
-    runs = tmp_path / "DEVICE_RUNS.jsonl"
-    records = [
-        # cpu results and null results are excluded; later tpu wins.
-        {"leg": "2pc", "result": {"device": "tpu", "rate": 1.0}},
-        {"leg": "2pc", "result": {"device": "tpu", "rate": 9.0}},
-        {"leg": "paxos3", "result": {"device": "cpu", "rate": 2.0}},
-        {"leg": "raft5", "result": None},
-        {"ab": "2pc-scatter", "result": {"device": "tpu", "rate": 3.0}},
-        {"flip_test": True, "result": {"device": "tpu", "winner": "x"}},
-        {"breakdown": "abd3o", "result": {"device": "tpu", "fused_wave_ms": 1}},
-    ]
-    runs.write_text("".join(json.dumps(r) + "\n" for r in records))
-    monkeypatch.setattr(bench, "REPO_DIR", str(tmp_path))
-    out = bench._sentinel_device_results()
-    assert set(out) == {"2pc", "2pc-scatter", "flip_test", "breakdown_abd3o"}
-    assert out["2pc"]["rate"] == 9.0  # retries: later entries win
-
-
-def test_sentinel_device_results_none_without_tpu(tmp_path, monkeypatch):
-    runs = tmp_path / "DEVICE_RUNS.jsonl"
-    runs.write_text('{"leg": "2pc", "result": {"device": "cpu"}}\n')
-    monkeypatch.setattr(bench, "REPO_DIR", str(tmp_path))
-    assert bench._sentinel_device_results() is None
 
 
 def test_evaluate_pipeline_choice_flags_slower_configured():
@@ -144,3 +90,19 @@ def test_service_report_round_trip(tmp_path):
     empty = tmp_path / "empty.json"
     empty.write_text("{}\n")
     assert service_report.main([str(empty), "--json"]) == 2
+
+
+def test_device_peak_is_an_exact_lookup():
+    """The roofline's peak comes from the exact device_kind: v5e's kind
+    as JAX reports it resolves, a prefix or an unknown TPU raises, and
+    a non-TPU backend has no peak."""
+    import pytest
+
+    from stateright_tpu.checker.breakdown import device_peak
+
+    assert device_peak("tpu", "TPU v5 lite")["hbm_gbps"] == 819.0
+    with pytest.raises(KeyError, match="TPU v5 lite pod"):
+        device_peak("tpu", "TPU v5 lite pod")
+    with pytest.raises(KeyError):
+        device_peak("tpu", "TPU v9")
+    assert device_peak("cpu", "cpu") is None

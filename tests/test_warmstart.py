@@ -94,7 +94,9 @@ def test_aot_store_refuses_stale_fence_and_corrupt_entries(tmp_path):
     path = store.entry_path("ns", ("sig",), "wave", (1, 2))
     with open(path, "rb") as f:
         entry = pickle.loads(f.read())
-    assert entry["fence"] == aot_fence()
+    fence = dict(entry["fence"])
+    assert fence.pop("execution_devices") == [jax.devices()[0].id]
+    assert fence == aot_fence()
 
     # Forge the fence: same file, wrong jax version — refused stale.
     entry["fence"] = dict(entry["fence"], jax_version="0.0.0-forged")
@@ -122,6 +124,34 @@ def test_aot_store_refuses_stale_fence_and_corrupt_entries(tmp_path):
         for p in registry_hygiene_problems(reg)
         if "aot_cache" in p
     ]
+
+
+def test_aot_store_loads_onto_the_compiled_device(tmp_path):
+    """An executable compiled for one device of several is saved with
+    that device in its fence and loads pinned to it: it runs there, not
+    on every local device (this JAX's default for a load without
+    ``execution_devices``)."""
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
+    devices = jax.devices()
+    assert len(devices) > 1, "conftest gives 8 virtual CPU devices"
+    target = devices[-1]
+    x = jax.device_put(jnp.int32(20), SingleDeviceSharding(target))
+    exe = jax.jit(lambda v: v + 1).lower(x).compile()
+    store = AotDiskStore(str(tmp_path / "aot"))
+    assert store.save_entry("ns", ("one",), "wave", (1,), exe)
+    with open(store.entry_path("ns", ("one",), "wave", (1,)), "rb") as f:
+        assert pickle.loads(f.read())["fence"]["execution_devices"] == [
+            target.id
+        ]
+
+    loaded, outcome = store.load_entry("ns", ("one",), "wave", (1,))
+    assert outcome == "hit"
+    assert loaded.runtime_executable().local_devices() == [target]
+    out = loaded(x)
+    assert int(out) == 21
+    assert out.devices() == {target}
 
 
 # ---------------------------------------------------------------------------
