@@ -64,7 +64,7 @@ from ..core.batch import BatchableModel
 from ..core.path import Path
 from ..ops.fingerprint import fp_to_int
 from ..ops.hashset import hashset_insert_unsorted, hashset_new
-from ..telemetry import device_step_annotation, get_tracer, metrics_registry
+from ..telemetry import get_tracer, metrics_registry
 from ..utils.faults import TenantFaultError, fault_point
 from .base import Checker
 from .tpu import checkpoint_header, validate_checkpoint_header
@@ -565,7 +565,7 @@ class SwarmEngine:
         with self._tracer.span(
             "swarm.wave", call=self._wave_calls, tenants=self._T,
             lanes=self._L, wave_steps=self._K,
-        ) as sp, device_step_annotation("swarm.wave", self._wave_calls):
+        ) as sp:
             self._carry = self._wave_fn(self._carry)
             stats = jax.device_get(self._carry["stats"])
             self._disc_found_host = np.asarray(self._carry["disc"]["found"])

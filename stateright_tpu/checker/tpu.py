@@ -62,12 +62,8 @@ from ..ops.hashset import (
     hashset_new,
 )
 from ..ops.ring import ring_export, ring_push, ring_rows, ring_take
-from ..telemetry import (
-    WaveInstruments,
-    device_step_annotation,
-    get_tracer,
-    metrics_registry,
-)
+from ..telemetry import CheckTracer, WaveInstruments, metrics_registry
+from ..telemetry.executables import TracedJit, exe_span
 from ..utils.faults import fault_point
 from .base import _NULL_CTX, Checker  # noqa: F401 - _NULL_CTX re-exported
 from .pipeline import HostPipeline
@@ -568,11 +564,17 @@ class TpuBfsChecker(Checker):
         self._properties = model.properties()
         # Run identity (checking-as-a-service): ``run_id=`` gives this
         # checker its own metrics registry (no instrument collisions
-        # between concurrent runs in one process) and stamps every trace
-        # span with the id so monitors can select this run's stream.
+        # between concurrent runs in one process). Every span of the check
+        # carries its ``check`` id (the run_id when given, so monitors can
+        # select this run's stream) and a parent in the check's tree.
         self.run_id = run_id
         self._registry = metrics_registry(run_id) if run_id else None
-        self._tracer = get_tracer(run_id)
+        self._tracer = CheckTracer(run_id)
+        # Executables this check already holds, by ("wave"|"drain", key):
+        # a later lookup of one is a reuse, not an acquisition.
+        self._exe_held = set()
+        self._drains = 0
+        self._fp_calls = 0
         self._conditions = model.packed_conditions()
         if len(self._conditions) != len(self._properties):
             raise ValueError(
@@ -661,6 +663,7 @@ class TpuBfsChecker(Checker):
         # until the first eviction; afterwards the table holds only the
         # working set plus re-claimed hot keys).
         self._l0_count = 0
+        self._drain_exit = None
         # Visited-set insert kernel for the sorted wave batches: "xla"
         # (gather/scatter probing, ops/hashset.py) or "pallas" (tile-sweep
         # DMA kernel, ops/pallas_hashset.py — measure both with
@@ -1077,20 +1080,31 @@ class TpuBfsChecker(Checker):
             self._aot_disk = store.binding(
                 aot_cache, self._aot_signature(), registry=self._registry
             )
-        self._jit_pool_zero = jax.jit(self._pool_zero, static_argnums=(0,))
+        def traced(kind, fn, **jit_kwargs):
+            return TracedJit(
+                jax.jit(fn, **jit_kwargs), self._tracer, "tpu_bfs.exe", kind
+            )
+
+        self._jit_pool_zero = traced(
+            "pool_zero", self._pool_zero, static_argnums=(0,)
+        )
         # The ring is rebound to the returned one; the pushed chunk's
         # buffers cannot alias the ring (scatter), so donating them would
         # only trade a copy for an unusable-donation warning.
-        self._jit_pool_push = jax.jit(self._pool_push, donate_argnums=(0,))
-        self._jit_pool_export = jax.jit(self._pool_export)
-        self._jit_init = jax.jit(self._init_wave, donate_argnums=(0,))
-        self._jit_take = jax.jit(self._take, static_argnums=(2,))
-        self._jit_finish = jax.jit(self._finish, static_argnums=(2,))
-        self._jit_materialize = jax.jit(self._materialize)
+        self._jit_pool_push = traced(
+            "pool_push", self._pool_push, donate_argnums=(0,)
+        )
+        self._jit_pool_export = traced("pool_export", self._pool_export)
+        self._jit_init = traced("init", self._init_wave, donate_argnums=(0,))
+        self._jit_take = traced("take", self._take, static_argnums=(2,))
+        self._jit_finish = traced("finish", self._finish, static_argnums=(2,))
+        self._jit_materialize = traced("materialize", self._materialize)
         # Only the destination table (arg 1) can alias the output; the old
         # table has a different shape and is freed by the caller's rebind.
-        self._jit_rehash = jax.jit(self._rehash, donate_argnums=(1,))
-        self._jit_fp_single = jax.jit(self._fp_fn)
+        self._jit_rehash = traced(
+            "rehash", self._rehash, donate_argnums=(1,)
+        )
+        self._jit_fp_single = traced("fp_single", self._fp_fn)
         # (in_width, bucket) -> jitted live-lane compaction (see
         # _compact_chunk).
         self._compact_exec = {}
@@ -1179,53 +1193,63 @@ class TpuBfsChecker(Checker):
         B = F * A
         eval_mask = mask & (depth < depth_cap)
 
+        # Stage scopes (``jax.named_scope``) name each stage's operations
+        # in the device trace's op metadata; they change no computation.
         # Property conditions on the frontier (the states being "popped").
-        cond_vals = [jax.vmap(c)(states) for c in self._conditions]
-        ebits_after = ebits
-        for pi, b in self._ebit.items():
-            ebits_after = jnp.where(
-                cond_vals[pi], ebits_after & ~jnp.uint32(1 << b), ebits_after
-            )
+        with jax.named_scope("tpu_bfs.cond"):
+            cond_vals = [jax.vmap(c)(states) for c in self._conditions]
+            ebits_after = ebits
+            for pi, b in self._ebit.items():
+                ebits_after = jnp.where(
+                    cond_vals[pi], ebits_after & ~jnp.uint32(1 << b),
+                    ebits_after,
+                )
 
         if self._use_fps:
             # Fingerprint-only expansion: candidate fps computed from the
             # parent's component hashes + per-transition deltas; no
             # candidate state arrays exist. Validity (including
             # within-boundary) is the model's contract (core/batch.py).
-            chi_g, clo_g, cvalid = jax.vmap(model.packed_expand_fps)(states)
-            cvalid = cvalid & eval_mask[:, None]
-            generated = cvalid.sum(dtype=jnp.int32)
-            terminal = eval_mask & ~cvalid.any(axis=1)
-            cvalid_flat = cvalid.reshape(B)
-            chi, clo = chi_g.reshape(B), clo_g.reshape(B)
+            with jax.named_scope("tpu_bfs.expand"):
+                chi_g, clo_g, cvalid = jax.vmap(model.packed_expand_fps)(
+                    states
+                )
+                cvalid = cvalid & eval_mask[:, None]
+                generated = cvalid.sum(dtype=jnp.int32)
+                terminal = eval_mask & ~cvalid.any(axis=1)
+                cvalid_flat = cvalid.reshape(B)
+                chi, clo = chi_g.reshape(B), clo_g.reshape(B)
             khi, klo = chi, clo
         else:
             # Expand the F × A action grid (packed_expand: per-class fast
             # path where the model provides one, else vmap of packed_step).
-            cand, cvalid = jax.vmap(model.packed_expand)(states)
-            cvalid = cvalid & eval_mask[:, None]
-            cvalid = cvalid & jax.vmap(
-                jax.vmap(model.packed_within_boundary)
-            )(cand)
-            generated = cvalid.sum(dtype=jnp.int32)
-            terminal = eval_mask & ~cvalid.any(axis=1)
+            with jax.named_scope("tpu_bfs.expand"):
+                cand, cvalid = jax.vmap(model.packed_expand)(states)
+                cvalid = cvalid & eval_mask[:, None]
+                cvalid = cvalid & jax.vmap(
+                    jax.vmap(model.packed_within_boundary)
+                )(cand)
+                generated = cvalid.sum(dtype=jnp.int32)
+                terminal = eval_mask & ~cvalid.any(axis=1)
 
             # Fingerprint all candidates, dedup within the wave by sorting.
-            cand_flat = jax.tree_util.tree_map(
-                lambda x: x.reshape((B,) + x.shape[2:]), cand
-            )
-            cvalid_flat = cvalid.reshape(B)
-            chi, clo = jax.vmap(self._fp_fn)(cand_flat)
-            # Dedup/visited-set keys (== the fingerprints unless symmetry is
-            # on, when they are orbit-minimum fingerprints). Frontier rows,
-            # parent pointers, and discoveries always carry the ORIGINAL
-            # fingerprints so paths replay through concrete states (the
-            # reference keeps original fps under symmetry too,
-            # src/checker/dfs.rs:300-309).
-            if self._symmetry_enabled:
-                khi, klo = self._key_fn(cand_flat)
-            else:
-                khi, klo = chi, clo
+            with jax.named_scope("tpu_bfs.fingerprint"):
+                cand_flat = jax.tree_util.tree_map(
+                    lambda x: x.reshape((B,) + x.shape[2:]), cand
+                )
+                cvalid_flat = cvalid.reshape(B)
+                chi, clo = jax.vmap(self._fp_fn)(cand_flat)
+                # Dedup/visited-set keys (== the fingerprints unless
+                # symmetry is on, when they are orbit-minimum
+                # fingerprints). Frontier rows, parent pointers, and
+                # discoveries always carry the ORIGINAL fingerprints so
+                # paths replay through concrete states (the reference
+                # keeps original fps under symmetry too,
+                # src/checker/dfs.rs:300-309).
+                if self._symmetry_enabled:
+                    khi, klo = self._key_fn(cand_flat)
+                else:
+                    khi, klo = chi, clo
         if elog is not None:
             # Device-native liveness: this wave's condition-false edge
             # and terminal rows, appended to the device store in-jit
@@ -1250,90 +1274,108 @@ class TpuBfsChecker(Checker):
             # lax.sort over the full F x A grid — 66% of the 2pc-7 wave
             # at F=8192 on CPU — disappears. Lanes keep natural order:
             # lane // A is the parent row directly.
-            table, fresh, _found, pending = hashset_insert_unsorted(
-                table, khi, klo, cvalid_flat
-            )
+            with jax.named_scope("tpu_bfs.insert"):
+                table, fresh, _found, pending = hashset_insert_unsorted(
+                    table, khi, klo, cvalid_flat
+                )
             sidx = jnp.arange(B, dtype=jnp.int32)
             shi, slo = khi, klo
         else:
-            shi = jnp.where(cvalid_flat, khi, _U32_MAX)
-            slo = jnp.where(cvalid_flat, klo, _U32_MAX)
-            shi, slo, sidx = jax.lax.sort(
-                (shi, slo, jnp.arange(B, dtype=jnp.int32)), num_keys=2
-            )
-            uniq = jnp.concatenate(
-                [
-                    jnp.ones((1,), bool),
-                    (shi[1:] != shi[:-1]) | (slo[1:] != slo[:-1]),
-                ]
-            )
-            wave_unique = cvalid_flat[sidx] & uniq
+            with jax.named_scope("tpu_bfs.dedup"):
+                shi = jnp.where(cvalid_flat, khi, _U32_MAX)
+                slo = jnp.where(cvalid_flat, klo, _U32_MAX)
+                shi, slo, sidx = jax.lax.sort(
+                    (shi, slo, jnp.arange(B, dtype=jnp.int32)), num_keys=2
+                )
+                uniq = jnp.concatenate(
+                    [
+                        jnp.ones((1,), bool),
+                        (shi[1:] != shi[:-1]) | (slo[1:] != slo[:-1]),
+                    ]
+                )
+                wave_unique = cvalid_flat[sidx] & uniq
 
             # Claim slots in the visited set; fresh lanes form the next
             # frontier.
-            table, fresh, _found, pending = self._insert_sorted(
-                table, shi, slo, wave_unique
-            )
-        overflow = pending.sum()
-        n_new = fresh.sum()
+            with jax.named_scope("tpu_bfs.insert"):
+                table, fresh, _found, pending = self._insert_sorted(
+                    table, shi, slo, wave_unique
+                )
+        with jax.named_scope("tpu_bfs.compact"):
+            overflow = pending.sum()
+            n_new = fresh.sum()
 
-        # Compact fresh lanes (sorted or natural order) into prefix slots.
-        pos = jnp.cumsum(fresh.astype(jnp.int32)) - 1
-        out_slot = jnp.where(fresh, pos, B)
-        zi = jnp.zeros((B,), jnp.int32)
-        zu = jnp.zeros((B,), jnp.uint32)
-        src_idx = zi.at[out_slot].set(sidx, mode="drop")
-        parent_row = sidx // A
-        new = {
-            "hi": zu.at[out_slot].set(chi[sidx], mode="drop"),
-            "lo": zu.at[out_slot].set(clo[sidx], mode="drop"),
-            "ebits": zu.at[out_slot].set(ebits_after[parent_row], mode="drop"),
-            "depth": zi.at[out_slot].set(depth[parent_row] + 1, mode="drop"),
-        }
-        if self._use_fps:
-            # Fresh lanes as (parent, action) references; the consumer
-            # materializes them F_max at a time (enqueue segments / the
-            # drain's segment loop) so only winners are ever built.
-            new["src_idx"] = src_idx
-        else:
-            new["states"] = jax.tree_util.tree_map(
-                lambda x: x[src_idx], cand_flat
-            )
-        out = {
-            "table": table,
-            "generated": generated,
-            "n_new": n_new,
-            "overflow": overflow,
-            "max_depth": jnp.max(jnp.where(mask, depth, 0)),
-            "new": new,
-            "parent_hi": zu.at[out_slot].set(hi[parent_row], mode="drop"),
-            "parent_lo": zu.at[out_slot].set(lo[parent_row], mode="drop"),
-        }
-        if self._symmetry_enabled:
-            # The visited-set keys the fresh lanes claimed (orbit-minimum
-            # fps) — checkpointing needs them to rebuild the table, since
-            # original fps cannot be re-keyed without states.
-            out["key_hi"] = zu.at[out_slot].set(shi, mode="drop")
-            out["key_lo"] = zu.at[out_slot].set(slo, mode="drop")
+            # Compact fresh lanes (sorted or natural order) into prefix
+            # slots.
+            pos = jnp.cumsum(fresh.astype(jnp.int32)) - 1
+            out_slot = jnp.where(fresh, pos, B)
+            zi = jnp.zeros((B,), jnp.int32)
+            zu = jnp.zeros((B,), jnp.uint32)
+            src_idx = zi.at[out_slot].set(sidx, mode="drop")
+            parent_row = sidx // A
+            new = {
+                "hi": zu.at[out_slot].set(chi[sidx], mode="drop"),
+                "lo": zu.at[out_slot].set(clo[sidx], mode="drop"),
+                "ebits": zu.at[out_slot].set(
+                    ebits_after[parent_row], mode="drop"
+                ),
+                "depth": zi.at[out_slot].set(
+                    depth[parent_row] + 1, mode="drop"
+                ),
+            }
+            if self._use_fps:
+                # Fresh lanes as (parent, action) references; the
+                # consumer materializes them F_max at a time (enqueue
+                # segments / the drain's segment loop) so only winners
+                # are ever built.
+                new["src_idx"] = src_idx
+            else:
+                new["states"] = jax.tree_util.tree_map(
+                    lambda x: x[src_idx], cand_flat
+                )
+            out = {
+                "table": table,
+                "generated": generated,
+                "n_new": n_new,
+                "overflow": overflow,
+                "max_depth": jnp.max(jnp.where(mask, depth, 0)),
+                "new": new,
+                "parent_hi": zu.at[out_slot].set(
+                    hi[parent_row], mode="drop"
+                ),
+                "parent_lo": zu.at[out_slot].set(
+                    lo[parent_row], mode="drop"
+                ),
+            }
+            if self._symmetry_enabled:
+                # The visited-set keys the fresh lanes claimed
+                # (orbit-minimum fps) — checkpointing needs them to
+                # rebuild the table, since original fps cannot be
+                # re-keyed without states.
+                out["key_hi"] = zu.at[out_slot].set(shi, mode="drop")
+                out["key_lo"] = zu.at[out_slot].set(slo, mode="drop")
 
-        # Per-property discovery scan over the evaluated frontier.
-        hits, fhis, flos = [], [], []
-        for i, p in enumerate(self._properties):
-            if p.expectation == Expectation.ALWAYS:
-                h = eval_mask & ~cond_vals[i]
-            elif p.expectation == Expectation.SOMETIMES:
-                h = eval_mask & cond_vals[i]
-            else:  # EVENTUALLY: unmet bit at a terminal state
-                b = self._ebit[i]
-                h = terminal & (((ebits_after >> jnp.uint32(b)) & 1) == 1)
-            idx = jnp.argmax(h)
-            hits.append(h.any())
-            fhis.append(hi[idx])
-            flos.append(lo[idx])
-        if self._properties:
-            out["prop_hit"] = jnp.stack(hits)
-            out["prop_hi"] = jnp.stack(fhis)
-            out["prop_lo"] = jnp.stack(flos)
+        with jax.named_scope("tpu_bfs.props"):
+            # Per-property discovery scan over the evaluated frontier.
+            hits, fhis, flos = [], [], []
+            for i, p in enumerate(self._properties):
+                if p.expectation == Expectation.ALWAYS:
+                    h = eval_mask & ~cond_vals[i]
+                elif p.expectation == Expectation.SOMETIMES:
+                    h = eval_mask & cond_vals[i]
+                else:  # EVENTUALLY: unmet bit at a terminal state
+                    b = self._ebit[i]
+                    h = terminal & (
+                        ((ebits_after >> jnp.uint32(b)) & 1) == 1
+                    )
+                idx = jnp.argmax(h)
+                hits.append(h.any())
+                fhis.append(hi[idx])
+                flos.append(lo[idx])
+            if self._properties:
+                out["prop_hit"] = jnp.stack(hits)
+                out["prop_hi"] = jnp.stack(fhis)
+                out["prop_lo"] = jnp.stack(flos)
         if self._cov is not None:
             # Coverage reductions (telemetry/coverage.py) fused into the
             # wave: per-action fired/fresh, per-property exercise,
@@ -1525,7 +1567,8 @@ class TpuBfsChecker(Checker):
                 elog=el,
             )
 
-        frontier0, head, count = self._pool_take(pool, head, count, F)
+        with jax.named_scope("tpu_bfs.ring"):
+            frontier0, head, count = self._pool_take(pool, head, count, F)
         out0 = wave_of(table, frontier0, elog)
         zl = jnp.zeros((L,), jnp.uint32)
         log0 = {
@@ -1599,55 +1642,59 @@ class TpuBfsChecker(Checker):
             new = o["new"]
             lanes = jnp.arange(B, dtype=jnp.int32)
             valid = lanes < n_new
-            slot = jnp.where(valid, c["log_n"] + lanes, L)
-            log = dict(c["log"])
-            log["child_hi"] = log["child_hi"].at[slot].set(
-                new["hi"], mode="drop"
-            )
-            log["child_lo"] = log["child_lo"].at[slot].set(
-                new["lo"], mode="drop"
-            )
-            log["parent_hi"] = log["parent_hi"].at[slot].set(
-                o["parent_hi"], mode="drop"
-            )
-            log["parent_lo"] = log["parent_lo"].at[slot].set(
-                o["parent_lo"], mode="drop"
-            )
-            if self._symmetry_enabled:
-                log["key_hi"] = log["key_hi"].at[slot].set(
-                    o["key_hi"], mode="drop"
+            with jax.named_scope("tpu_bfs.log"):
+                slot = jnp.where(valid, c["log_n"] + lanes, L)
+                log = dict(c["log"])
+                log["child_hi"] = log["child_hi"].at[slot].set(
+                    new["hi"], mode="drop"
                 )
-                log["key_lo"] = log["key_lo"].at[slot].set(
-                    o["key_lo"], mode="drop"
+                log["child_lo"] = log["child_lo"].at[slot].set(
+                    new["lo"], mode="drop"
                 )
+                log["parent_hi"] = log["parent_hi"].at[slot].set(
+                    o["parent_hi"], mode="drop"
+                )
+                log["parent_lo"] = log["parent_lo"].at[slot].set(
+                    o["parent_lo"], mode="drop"
+                )
+                if self._symmetry_enabled:
+                    log["key_hi"] = log["key_hi"].at[slot].set(
+                        o["key_hi"], mode="drop"
+                    )
+                    log["key_lo"] = log["key_lo"].at[slot].set(
+                        o["key_lo"], mode="drop"
+                    )
             # Push the fresh (compacted-prefix) lanes at the ring tail, then
             # dequeue the next frontier from the head — strict FIFO keeps
             # exact BFS order, so parent pointers stay shortest-path.
-            if self._use_fps:
-                pool, count = self._pool_push_fps(
-                    c["pool"],
-                    c["head"],
-                    c["count"],
-                    new,
-                    c["frontier"]["states"],
-                    n_new,
-                    F,
+            with jax.named_scope("tpu_bfs.ring"):
+                if self._use_fps:
+                    pool, count = self._pool_push_fps(
+                        c["pool"],
+                        c["head"],
+                        c["count"],
+                        new,
+                        c["frontier"]["states"],
+                        n_new,
+                        F,
+                    )
+                else:
+                    pool, count = self._pool_push(
+                        c["pool"],
+                        c["head"],
+                        c["count"],
+                        {
+                            "states": new["states"],
+                            "hi": new["hi"],
+                            "lo": new["lo"],
+                            "ebits": new["ebits"],
+                            "depth": new["depth"],
+                            "mask": valid,
+                        },
+                    )
+                frontier, head, count = self._pool_take(
+                    pool, c["head"], count, F
                 )
-            else:
-                pool, count = self._pool_push(
-                    c["pool"],
-                    c["head"],
-                    c["count"],
-                    {
-                        "states": new["states"],
-                        "hi": new["hi"],
-                        "lo": new["lo"],
-                        "ebits": new["ebits"],
-                        "depth": new["depth"],
-                        "mask": valid,
-                    },
-                )
-            frontier, head, count = self._pool_take(pool, c["head"], count, F)
             nxt = {
                 "pool": pool,
                 "head": head,
@@ -1755,23 +1802,30 @@ class TpuBfsChecker(Checker):
 
     def _run(self):
         try:
-            if self._profile_dir:
-                jax.profiler.start_trace(self._profile_dir)
+            # The check's root span: every span of this check descends
+            # from it, on this thread and on its caller's (discoveries()).
+            with self._tracer.span("tpu_bfs.check") as check:
+                self._tracer.root = check.id
                 try:
-                    self._explore()
+                    if self._profile_dir:
+                        jax.profiler.start_trace(self._profile_dir)
+                        try:
+                            self._explore()
+                        finally:
+                            jax.profiler.stop_trace()
+                    else:
+                        self._explore()
+                except BaseException as e:  # noqa: BLE001 - worker_error
+                    self._error = e
+                    self._abort_attribution()
                 finally:
-                    jax.profiler.stop_trace()
-            else:
-                self._explore()
-        except BaseException as e:  # noqa: BLE001 - surfaced via worker_error
-            self._error = e
-            self._abort_attribution()
+                    # The pipeline must be quiescent before done is
+                    # observable: counters/logs a late verdict would
+                    # mutate are read the moment join() returns.
+                    self._shutdown_pipeline()
+                    self._finalize_coverage(set(self._discoveries_fp))
+                    check.set(drains=self._drains, unique=self._unique_count)
         finally:
-            # The pipeline must be quiescent before done is observable:
-            # counters/logs a late verdict would mutate are read the
-            # moment join() returns.
-            self._shutdown_pipeline()
-            self._finalize_coverage(set(self._discoveries_fp))
             self._done_event.set()
 
     def _grow_table(self, table, min_capacity, defer_evict=False):
@@ -1798,7 +1852,9 @@ class TpuBfsChecker(Checker):
                 )
                 if self._attr is not None:
                     self._attr.fence(new_table)
-            if not int(leftover):
+                # Inside the span: reading the count waits for the rehash.
+                leftover = int(leftover)
+            if not leftover:
                 break
             # A pathological key cluster can exhaust the probe cap during
             # rehash; that costs capacity (the next doubling shortens
@@ -1919,7 +1975,8 @@ class TpuBfsChecker(Checker):
         if self._resume_from is not None:
             table, queue = self._restore(self._resume_from)
         else:
-            table, queue = self._seed()
+            with self._tracer.span("tpu_bfs.seed"):
+                table, queue = self._seed()
         depth_cap = jnp.int32(self._depth_cap)
         # Deep drain is off when a visitor needs per-chunk callbacks or a
         # target caps the run (overshoot would span whole drains instead of
@@ -1988,7 +2045,9 @@ class TpuBfsChecker(Checker):
                 )
                 return out
 
-            fn = jax.jit(compact)
+            fn = TracedJit(
+                jax.jit(compact), self._tracer, "tpu_bfs.exe", "compact"
+            )
             self._compact_exec[key] = fn
         return fn(chunk)
 
@@ -2050,32 +2109,12 @@ class TpuBfsChecker(Checker):
             args = (table, self._elog) + args[1:]
         key = (table.shape[0], chunk["hi"].shape[0])
         exe = self._wave_exec.get(key)
-        if exe is not None and self._aot_disk is not None:
+        if exe is None or ("wave", key) not in self._exe_held:
+            exe = self._obtain_wave(key, exe, args)
+        elif self._aot_disk is not None:
             # Warm-memory / cold-disk: backfill so a later fresh process
             # still finds the artifact (one existence probe per key).
             self._aot_disk.ensure("wave", key, exe)
-        if exe is None and self._aot_disk is not None:
-            # Disk tier of the AOT cache: a fenced hit deserializes the
-            # executable OUTSIDE the compile phase/span — the whole
-            # point is that the attribution ledger records no compile.
-            exe = self._aot_disk.load("wave", key)
-            if exe is not None:
-                self._wave_exec[key] = exe
-        if exe is None:
-            t0 = time.perf_counter()
-            # AOT-cache miss == a compile is about to happen: the ONE
-            # place the attribution engine can detect first-dispatch rung
-            # compiles (the cache hit path never enters this branch).
-            with self._tracer.span(
-                "tpu_bfs.compile", table_capacity=key[0], frontier=key[1]
-            ), self._phase("compile"):
-                exe = self._jit_wave.lower(*args).compile()
-            self._wave_exec[key] = exe
-            if self.warmup_seconds is not None:
-                self.warmup_seconds += time.perf_counter() - t0
-                self._wi.warmup.set(self.warmup_seconds)
-            if self._aot_disk is not None:
-                self._aot_disk.save("wave", key, exe)
         if self._attr is None:
             out = exe(*args)
         else:
@@ -2513,9 +2552,7 @@ class TpuBfsChecker(Checker):
                         defer_evict=pipe is not None,
                     )
                 if pipe is None:
-                    with self._tracer.span(
-                        "tpu_bfs.wave", wave=chunks
-                    ) as sp, device_step_annotation("tpu_bfs.wave", chunks):
+                    with self._tracer.span("tpu_bfs.wave", wave=chunks) as sp:
                         table, _ = self._consume_wave(
                             table, None, chunk, queue, depth_cap, span=sp
                         )
@@ -2523,10 +2560,9 @@ class TpuBfsChecker(Checker):
                     # Bounded pending-verdict lane set: at most
                     # max_pending waves of device output pinned at once.
                     pipe.throttle()
-                    with device_step_annotation("tpu_bfs.wave", chunks):
-                        table = self._consume_wave_async(
-                            table, chunk, queue, depth_cap, chunks
-                        )
+                    table = self._consume_wave_async(
+                        table, chunk, queue, depth_cap, chunks
+                    )
             if self.warmup_seconds is None:
                 self._set_warmup(time.perf_counter() - t_start)
         if pipe is not None:
@@ -2538,7 +2574,21 @@ class TpuBfsChecker(Checker):
     def _explore_deep(self, table, queue, depth_cap, t_start):
         """Deep-drain host loop: keeps the pending frontier in the device
         ring and re-enters ``_deep_drain`` until the space is exhausted,
-        paying host round trips only at drain exits."""
+        paying host round trips only at drain exits. Each exit, from a
+        drain's result to the next drain's dispatch (or the loop's end),
+        is one ``tpu_bfs.drain_exit`` span."""
+        try:
+            return self._drain_loop(table, queue, depth_cap, t_start)
+        finally:
+            self._close_drain_exit()
+
+    def _close_drain_exit(self, **args):
+        span, self._drain_exit = self._drain_exit, None
+        if span is not None:
+            span.set(**args)
+            span.__exit__(None, None, None)
+
+    def _drain_loop(self, table, queue, depth_cap, t_start):
         props = self._properties
         if not props:
             return
@@ -2599,6 +2649,7 @@ class TpuBfsChecker(Checker):
             # exact BFS inherently holds the whole pending frontier, just
             # like the reference's host queue. Push dispatches stay
             # device-side (no blocking transfer).
+            refill_chunks = len(queue)
             while queue:
                 if pool_count + self._F_max > self._pool_capacity:
                     # The host bound overcounts (F_max per push); refresh it
@@ -2641,6 +2692,7 @@ class TpuBfsChecker(Checker):
                         )
                     last_checkpoint = time.perf_counter()
                 drains += 1
+                self._drains = drains
                 if (self._l0_count + B) > _MAX_LOAD * self._capacity:
                     table = self._grow_table(
                         table, _pow2ceil(int((self._l0_count + B) / _MAX_LOAD))
@@ -2722,10 +2774,11 @@ class TpuBfsChecker(Checker):
                 # _call_wave's site): the ring still holds the frontier,
                 # so nothing of this drain is half-applied on a raise.
                 fault_point("device.wave")
+                self._close_drain_exit(refill_chunks=refill_chunks)
                 drain_span = self._tracer.span(
                     "tpu_bfs.drain", drain=drains, bucket=width
                 )
-                with drain_span, device_step_annotation("tpu_bfs.drain", drains):
+                with drain_span:
                     with self._phase(self._device_phase):
                         res = exe(*args)
                         if self._attr is not None:
@@ -2780,6 +2833,13 @@ class TpuBfsChecker(Checker):
                         bucket=width,
                         compaction_ratio=compaction,
                     )
+                # The drain's result is in (its stats pull above waited
+                # for the device): host time until the next dispatch.
+                self._drain_exit = self._tracer.span(
+                    "tpu_bfs.drain_exit", drain=drains, log_bytes=0,
+                    refill_chunks=0,
+                )
+                self._drain_exit.__enter__()
                 pool, head, count = res["pool"], res["head"], res["count"]
                 if self._live_enabled:
                     # Rebind the donated edge log to the drain's output
@@ -2797,6 +2857,7 @@ class TpuBfsChecker(Checker):
                 if log_n:
                     # The whole drain's parent-fp stream in one transfer.
                     pack = np.asarray(res["log_pack"][:, :log_n])
+                    self._drain_exit.set(log_bytes=pack.nbytes)
                     self._wave_log.append(
                         (fp64_pairs(pack[0], pack[1]), fp64_pairs(pack[2], pack[3]))
                     )
@@ -2833,6 +2894,56 @@ class TpuBfsChecker(Checker):
         )
         return newq
 
+    def _obtain_wave(self, key, exe, args):
+        """A wave executable this check does not hold yet, keyed on (table
+        rows, bucket width): ``exe`` from the shared AOT cache, else the
+        disk AOT store, else a compile (see ``_call_wave``)."""
+        with exe_span(
+            self._tracer, "tpu_bfs.exe", "wave",
+            table_capacity=key[0], frontier=key[1],
+        ) as sp:
+            if exe is not None:
+                sp.set(source="memory")
+                if self._aot_disk is not None:
+                    self._aot_disk.ensure("wave", key, exe)
+            elif self._aot_disk is not None:
+                # Disk tier of the AOT cache: a fenced hit deserializes
+                # the executable outside the compile phase and span — the
+                # whole point is that the attribution ledger records no
+                # compile.
+                t0 = time.perf_counter()
+                exe = self._aot_disk.load("wave", key)
+                if exe is not None:
+                    self._wave_exec[key] = exe
+                    sp.set(
+                        source="disk",
+                        build_ms=(time.perf_counter() - t0) * 1e3,
+                    )
+            if exe is None:
+                t0 = time.perf_counter()
+                # AOT-cache miss == a compile is about to happen: the ONE
+                # place the attribution engine can detect first-dispatch
+                # rung compiles (the cache hit path never enters here).
+                with self._tracer.span(
+                    "tpu_bfs.compile", table_capacity=key[0],
+                    frontier=key[1],
+                ), self._phase("compile"):
+                    lowered = self._jit_wave.lower(*args)
+                    t1 = time.perf_counter()
+                    exe = lowered.compile()
+                sp.set(
+                    trace_ms=(t1 - t0) * 1e3,
+                    build_ms=(time.perf_counter() - t1) * 1e3,
+                )
+                self._wave_exec[key] = exe
+                if self.warmup_seconds is not None:
+                    self.warmup_seconds += time.perf_counter() - t0
+                    self._wi.warmup.set(self.warmup_seconds)
+                if self._aot_disk is not None:
+                    self._aot_disk.save("wave", key, exe)
+        self._exe_held.add(("wave", key))
+        return exe
+
     def _drain_exe(self, width, args, t_start):
         """The AOT-compiled deep drain for one ladder rung, keyed on
         (width, table rows, pool capacity) so table/ring growth recompiles
@@ -2841,49 +2952,76 @@ class TpuBfsChecker(Checker):
         steady-state window honest."""
         key = (width, args[0].shape[0], self._pool_capacity)
         exe = self._drain_exec.get(key)
-        if exe is not None and self._aot_disk is not None:
-            # Warm-memory / cold-disk backfill, same as the wave site.
-            self._aot_disk.ensure("drain", key, exe)
-        if exe is None and self._aot_disk is not None:
-            # Disk tier (warm-start plane): a fenced hit loads the rung
-            # outside the compile phase — cross-process warm starts
-            # record zero compile, exactly like the in-memory hit below.
-            exe = self._aot_disk.load("drain", key)
-            if exe is not None:
-                self._drain_exec[key] = exe
-        if exe is not None and self.warmup_seconds is None:
-            # Warm start (shared AOT cache hit on the very first drain):
-            # stamp the setup-only warmup now. Leaving it None would
-            # both under-report the warm/steady split and make the
-            # service's stall watchdog treat the whole run as warmup
-            # (its pet condition defers to an unstamped warmup).
-            self._set_warmup(time.perf_counter() - t_start)
-        if exe is None:
-            jit_fn = self._drain_jits.get(width)
-            if jit_fn is None:
-
-                def fn(*a, _w=width):
-                    return self._deep_drain(_w, *a)
-
-                donate = (0, 1, 7) if self._live_enabled else (0, 1)
-                jit_fn = jax.jit(fn, donate_argnums=donate)
-                self._drain_jits[width] = jit_fn
-            t0 = time.perf_counter()
-            # AOT-cache miss: the drain rung is about to compile — the
-            # attribution engine's compile-detection site for drains.
-            with self._tracer.span(
-                "tpu_bfs.compile", kind="drain", bucket=width,
-                table_capacity=key[1],
-            ), self._phase("compile"):
-                exe = jit_fn.lower(*args).compile()
-            self._drain_exec[key] = exe
-            if self.warmup_seconds is None:
-                self._set_warmup(time.perf_counter() - t_start)
-            else:
-                self.warmup_seconds += time.perf_counter() - t0
-                self._wi.warmup.set(self.warmup_seconds)
+        if exe is not None and ("drain", key) in self._exe_held:
             if self._aot_disk is not None:
-                self._aot_disk.save("drain", key, exe)
+                # Warm-memory / cold-disk backfill, same as the wave site.
+                self._aot_disk.ensure("drain", key, exe)
+            return exe
+        with exe_span(
+            self._tracer, "tpu_bfs.exe", "drain",
+            bucket=width, table_capacity=key[1],
+        ) as sp:
+            if exe is not None:
+                sp.set(source="memory")
+                if self._aot_disk is not None:
+                    self._aot_disk.ensure("drain", key, exe)
+            elif self._aot_disk is not None:
+                # Disk tier (warm-start plane): a fenced hit loads the
+                # rung outside the compile phase — cross-process warm
+                # starts record zero compile, exactly like the in-memory
+                # hit above.
+                t0 = time.perf_counter()
+                exe = self._aot_disk.load("drain", key)
+                if exe is not None:
+                    self._drain_exec[key] = exe
+                    sp.set(
+                        source="disk",
+                        build_ms=(time.perf_counter() - t0) * 1e3,
+                    )
+            if exe is not None and self.warmup_seconds is None:
+                # Warm start (shared AOT cache hit on the very first
+                # drain): stamp the setup-only warmup now. Leaving it None
+                # would both under-report the warm/steady split and make
+                # the service's stall watchdog treat the whole run as
+                # warmup (its pet condition defers to an unstamped
+                # warmup).
+                self._set_warmup(time.perf_counter() - t_start)
+            if exe is None:
+                jit_fn = self._drain_jits.get(width)
+                if jit_fn is None:
+
+                    def fn(*a, _w=width):
+                        return self._deep_drain(_w, *a)
+
+                    # The executable's name in device traces, one per
+                    # ladder rung (``jit(fn)`` otherwise).
+                    fn.__name__ = fn.__qualname__ = f"tpu_bfs_drain_w{width}"
+                    donate = (0, 1, 7) if self._live_enabled else (0, 1)
+                    jit_fn = jax.jit(fn, donate_argnums=donate)
+                    self._drain_jits[width] = jit_fn
+                t0 = time.perf_counter()
+                # AOT-cache miss: the drain rung is about to compile — the
+                # attribution engine's compile-detection site for drains.
+                with self._tracer.span(
+                    "tpu_bfs.compile", kind="drain", bucket=width,
+                    table_capacity=key[1],
+                ), self._phase("compile"):
+                    lowered = jit_fn.lower(*args)
+                    t1 = time.perf_counter()
+                    exe = lowered.compile()
+                sp.set(
+                    trace_ms=(t1 - t0) * 1e3,
+                    build_ms=(time.perf_counter() - t1) * 1e3,
+                )
+                self._drain_exec[key] = exe
+                if self.warmup_seconds is None:
+                    self._set_warmup(time.perf_counter() - t_start)
+                else:
+                    self.warmup_seconds += time.perf_counter() - t0
+                    self._wi.warmup.set(self.warmup_seconds)
+                if self._aot_disk is not None:
+                    self._aot_disk.save("drain", key, exe)
+        self._exe_held.add(("drain", key))
         return exe
 
     def _export_pool_chunks(self, pool, head, count):
@@ -3213,18 +3351,24 @@ class TpuBfsChecker(Checker):
     # -- path reconstruction ----------------------------------------------
 
     def _host_fp(self, host_state) -> int:
+        self._fp_calls += 1
         hi, lo = self._jit_fp_single(self._model.pack_state(host_state))
         return fp_to_int(hi, lo)
 
-    def _ingest_wave_log(self):
+    def _ingest_wave_log(self) -> int:
+        """Moves the logged (child, parent) fps into the parent store;
+        returns how many entries this call ingested."""
         # Raced by the worker (visitor reconstruction) and the user thread
         # (mid-run discoveries()); must not skip a wave. First-writer-wins
         # inserts keep the shortest-path parent.
+        entries = 0
         with self._ingest_lock:
             while self._ingested < len(self._wave_log):
                 children, parents = self._wave_log[self._ingested]
                 self._store.insert_batch(children, parents)
                 self._ingested += 1
+                entries += len(children)
+        return entries
 
     def _reconstruct(self, fp: int) -> Path:
         self._ingest_wave_log()
@@ -3274,16 +3418,21 @@ class TpuBfsChecker(Checker):
     supports_device_liveness = True
 
     def discoveries(self) -> Dict[str, Path]:
-        out = {
-            name: self._reconstruct(fp)
-            for name, fp in list(self._discoveries_fp.items())
-        }
-        out = self._with_device_liveness(out)
-        return self._with_lassos(
-            out,
-            self._done_event.is_set(),
-            set(self._discoveries_fp) | set(self._live_paths),
-        )
+        with self._tracer.span("tpu_bfs.paths") as sp:
+            fp_calls = self._fp_calls
+            entries = self._ingest_wave_log()
+            out = {
+                name: self._reconstruct(fp)
+                for name, fp in list(self._discoveries_fp.items())
+            }
+            out = self._with_device_liveness(out)
+            out = self._with_lassos(
+                out,
+                self._done_event.is_set(),
+                set(self._discoveries_fp) | set(self._live_paths),
+            )
+            sp.set(log_entries=entries, fp_calls=self._fp_calls - fp_calls)
+        return out
 
     def handles(self) -> List[threading.Thread]:
         handles, self._handles = self._handles, []

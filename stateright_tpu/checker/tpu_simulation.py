@@ -34,7 +34,7 @@ from ..core.batch import BatchableModel
 from ..core.model import Expectation
 from ..core.path import Path
 from ..ops.fingerprint import fingerprint_state, fp_to_int
-from ..telemetry import device_step_annotation, get_tracer, metrics_registry
+from ..telemetry import get_tracer, metrics_registry
 from .base import Checker
 
 _NEG_INF = -1e30
@@ -423,7 +423,7 @@ class TpuSimulationChecker(Checker):
             with tracer.span(
                 "tpu_sim.steps", call=calls, lanes=self._L,
                 steps_per_call=self._K,
-            ) as sp, device_step_annotation("tpu_sim.steps", calls):
+            ) as sp:
                 carry = self._jit_steps(carry)
                 lanes, stats, disc = carry
                 step_count = int(stats["count"])

@@ -58,7 +58,6 @@ from ..ops.ring import ring_export, ring_push, ring_rows, ring_take
 from ..telemetry import (
     CommsInstruments,
     WaveInstruments,
-    device_step_annotation,
     get_tracer,
     metrics_registry,
 )
@@ -1930,9 +1929,7 @@ class ShardedTpuBfsChecker(Checker):
         wave_generated = 0
         wave_new = 0
         self._wave_stale = 0
-        with self._tracer.span(
-            "sharded_bfs.wave", wave=chunks
-        ) as sp, device_step_annotation("sharded_bfs.wave", chunks):
+        with self._tracer.span("sharded_bfs.wave", wave=chunks) as sp:
             while True:
                 wave = self._call_wave(table, dev, depth_cap)
                 table = wave["table"]
@@ -1989,68 +1986,67 @@ class ShardedTpuBfsChecker(Checker):
         synchronous order; see TpuBfsChecker._consume_wave_async)."""
         attempt = 0
         ctx = {"wave_new": 0, "stale": 0, "generated": 0}
-        with device_step_annotation("sharded_bfs.wave", chunks):
-            while True:
-                wave = self._call_wave(table, dev, depth_cap)
-                table = wave["table"]
-                if attempt == 0:
-                    ctx["generated"] = self._apply_wave_stats(wave)
-                if self._cov is not None:
-                    self._cov.consume_device(
-                        np.asarray(self._pull(wave["cov"])).sum(axis=0),
-                        self._cov_layout,
-                        first_attempt=(attempt == 0),
-                        max_depth=self._max_depth,
-                    )
-                n_new = self._pull(wave["n_new"])
-                total = int(n_new.sum())
-                self._l0_count += total
-                final = not int(self._pull(wave["overflow"]).sum())
-                # Only jobs that can grow the pool hold up the
-                # coalescing barrier (see _explore_waves); count this
-                # one in BEFORE it is queued — incrementing after
-                # submit could let the job's decrement land first and
-                # the barrier miss a genuinely pending verdict.
-                with self._pool_lock:
-                    self._inflight_verdicts += 1
-                try:
-                    # Point-in-time captures: the live l0/capacity/depth
-                    # fields may describe a later wave by verdict time.
-                    self._pipe.submit(
-                        lambda w=wave, nn=n_new, t=total, f=final,
-                        warm=self.warmup_seconds is not None,
-                        st=(
-                            self._l0_count,
-                            self._n * self._cap_loc,
-                            self._max_depth,
-                        ),
-                        cm=self._last_comms:
-                            self._harvest_verdict(
-                                ctx, w, nn, t, f, chunks, width, bucket,
-                                got, warm, st, cm,
-                            )
-                    )
-                except BaseException:
-                    # A poisoned submit never enqueues the job (whose
-                    # finally would decrement) — rebalance here.
-                    with self._pool_lock:
-                        self._inflight_verdicts -= 1
-                    raise
-                if final:
-                    if self._cov is not None:
-                        self._cov.emit_wave_span()
-                    return table
-                if self._max_cap_loc is not None and attempt >= 8:
-                    raise RuntimeError(
-                        "a single wave's routed keys overflow one "
-                        "budget-capped shard after repeated "
-                        "evictions; raise hbm_budget_mib or shrink "
-                        "frontier_per_device"
-                    )
-                table = self._grow_table(
-                    table, self._cap_loc * 2, defer_evict=True
+        while True:
+            wave = self._call_wave(table, dev, depth_cap)
+            table = wave["table"]
+            if attempt == 0:
+                ctx["generated"] = self._apply_wave_stats(wave)
+            if self._cov is not None:
+                self._cov.consume_device(
+                    np.asarray(self._pull(wave["cov"])).sum(axis=0),
+                    self._cov_layout,
+                    first_attempt=(attempt == 0),
+                    max_depth=self._max_depth,
                 )
-                attempt += 1
+            n_new = self._pull(wave["n_new"])
+            total = int(n_new.sum())
+            self._l0_count += total
+            final = not int(self._pull(wave["overflow"]).sum())
+            # Only jobs that can grow the pool hold up the
+            # coalescing barrier (see _explore_waves); count this
+            # one in BEFORE it is queued — incrementing after
+            # submit could let the job's decrement land first and
+            # the barrier miss a genuinely pending verdict.
+            with self._pool_lock:
+                self._inflight_verdicts += 1
+            try:
+                # Point-in-time captures: the live l0/capacity/depth
+                # fields may describe a later wave by verdict time.
+                self._pipe.submit(
+                    lambda w=wave, nn=n_new, t=total, f=final,
+                    warm=self.warmup_seconds is not None,
+                    st=(
+                        self._l0_count,
+                        self._n * self._cap_loc,
+                        self._max_depth,
+                    ),
+                    cm=self._last_comms:
+                        self._harvest_verdict(
+                            ctx, w, nn, t, f, chunks, width, bucket,
+                            got, warm, st, cm,
+                        )
+                )
+            except BaseException:
+                # A poisoned submit never enqueues the job (whose
+                # finally would decrement) — rebalance here.
+                with self._pool_lock:
+                    self._inflight_verdicts -= 1
+                raise
+            if final:
+                if self._cov is not None:
+                    self._cov.emit_wave_span()
+                return table
+            if self._max_cap_loc is not None and attempt >= 8:
+                raise RuntimeError(
+                    "a single wave's routed keys overflow one "
+                    "budget-capped shard after repeated "
+                    "evictions; raise hbm_budget_mib or shrink "
+                    "frontier_per_device"
+                )
+            table = self._grow_table(
+                table, self._cap_loc * 2, defer_evict=True
+            )
+            attempt += 1
 
     def _harvest_verdict(self, ctx, wave, n_new, total, final, wave_no,
                          width, bucket, got, warm, state, comms=None):
@@ -2426,9 +2422,7 @@ class ShardedTpuBfsChecker(Checker):
                         )
                         self._wi.warmup.set(self.warmup_seconds)
                 drain_span = self._tracer.span("sharded_bfs.drain", drain=drains)
-                with drain_span, device_step_annotation(
-                    "sharded_bfs.drain", drains
-                ):
+                with drain_span:
                     with self._phase("device"):
                         res = self._jit_deep_drain(*args)
                         if self._attr is not None:

@@ -297,6 +297,7 @@ class PackedRegisterLinearizability:
         replaces: ``/root/reference/src/semantics/linearizability.rs:
         179-284`` (the recursive search the reference re-runs per state).
         """
+        import jax
         import jax.numpy as jnp
 
         C, O = self.C, self.O
@@ -319,54 +320,59 @@ class PackedRegisterLinearizability:
         BITS = jnp.asarray((1 << np.arange(V)).astype(np.uint32))
 
         def fn(hist):
-            valid, counts, slots = self._split(hist)
-            U = jnp.concatenate(
-                [
-                    jnp.full((1,), default, jnp.uint32),
-                    slots[:, :, 1].reshape(-1).astype(jnp.uint32),
+            # Names the DP's operations in device traces.
+            with jax.named_scope("history"):
+                valid, counts, slots = self._split(hist)
+                U = jnp.concatenate(
+                    [
+                        jnp.full((1,), default, jnp.uint32),
+                        slots[:, :, 1].reshape(-1).astype(jnp.uint32),
+                    ]
+                )
+
+                def eq_bits(v):
+                    return jnp.where(U == v, BITS, jnp.uint32(0)).sum()
+
+                EB = [
+                    [eq_bits(slots[t, j, 1]) for j in range(O)]
+                    for t in range(C)
                 ]
-            )
-
-            def eq_bits(v):
-                return jnp.where(U == v, BITS, jnp.uint32(0)).sum()
-
-            EB = [[eq_bits(slots[t, j, 1]) for j in range(O)] for t in range(C)]
-            masks = [jnp.uint32(0)] * len(nodes)
-            masks[0] = eq_bits(jnp.uint32(default))
-            accept = jnp.bool_(False)
-            for i, c in enumerate(nodes):
-                m = masks[i]
-                done = jnp.bool_(True)
-                for t in range(C):
-                    done &= jnp.uint32(c[t]) >= counts[t]
-                accept |= done & (m != 0)
-                for t in range(C):
-                    j = c[t]
-                    if j >= O:
-                        continue
-                    succ = node_idx[c[:t] + (j + 1,) + c[t + 1 :]]
-                    kind = slots[t, j, 0]
-                    constr = slots[t, j, 2:]
-                    completed = jnp.uint32(j) < counts[t]
-                    inflight = (jnp.uint32(j) == counts[t]) & (kind != 0)
-                    present = completed | inflight
-                    cvec = jnp.asarray(np.array(c, np.uint32))
-                    rt_ok = (
-                        (cvec >= constr).all() if real_time
-                        else jnp.bool_(True)
-                    )
-                    eb = EB[t][j]
-                    write_m = jnp.where(m != 0, eb, jnp.uint32(0))
-                    # In-flight reads generate their return: no constraint.
-                    read_m = jnp.where(completed, m & eb, m)
-                    m_next = jnp.where(
-                        kind == 1, write_m, jnp.where(kind == 2, read_m, m)
-                    )
-                    contrib = jnp.where(
-                        present & rt_ok, m_next, jnp.uint32(0)
-                    )
-                    masks[succ] = masks[succ] | contrib
-            return (valid == 1) & accept
+                masks = [jnp.uint32(0)] * len(nodes)
+                masks[0] = eq_bits(jnp.uint32(default))
+                accept = jnp.bool_(False)
+                for i, c in enumerate(nodes):
+                    m = masks[i]
+                    done = jnp.bool_(True)
+                    for t in range(C):
+                        done &= jnp.uint32(c[t]) >= counts[t]
+                    accept |= done & (m != 0)
+                    for t in range(C):
+                        j = c[t]
+                        if j >= O:
+                            continue
+                        succ = node_idx[c[:t] + (j + 1,) + c[t + 1 :]]
+                        kind = slots[t, j, 0]
+                        constr = slots[t, j, 2:]
+                        completed = jnp.uint32(j) < counts[t]
+                        inflight = (jnp.uint32(j) == counts[t]) & (kind != 0)
+                        present = completed | inflight
+                        cvec = jnp.asarray(np.array(c, np.uint32))
+                        rt_ok = (
+                            (cvec >= constr).all() if real_time
+                            else jnp.bool_(True)
+                        )
+                        eb = EB[t][j]
+                        write_m = jnp.where(m != 0, eb, jnp.uint32(0))
+                        # In-flight reads generate their return: no constraint.
+                        read_m = jnp.where(completed, m & eb, m)
+                        m_next = jnp.where(
+                            kind == 1, write_m, jnp.where(kind == 2, read_m, m)
+                        )
+                        contrib = jnp.where(
+                            present & rt_ok, m_next, jnp.uint32(0)
+                        )
+                        masks[succ] = masks[succ] | contrib
+                return (valid == 1) & accept
 
         return fn
 

@@ -8,8 +8,9 @@ Three parts, all process-local and always importable:
 - ``trace``: span and instant events with monotonic timestamps, an
   always-on in-memory ring buffer, an opt-in JSONL sink, and a Chrome
   trace-event exporter (loadable in Perfetto / ``chrome://tracing``),
-  plus an optional ``jax.profiler`` bridge so host spans line up with
-  XLA device traces.
+  each span bridged into a ``jax.profiler`` annotation so host spans
+  line up with XLA device traces, and parent ids that make one check's
+  spans a tree (``CheckTracer``).
 - ``attribution``: the opt-in wave-timeline attribution engine
   (``WaveAttribution``) — fenced per-wave wall-clock classified into
   device/host phases, with the overlap-headroom ledger
@@ -40,13 +41,12 @@ from .metrics import (
     run_registries,
 )
 from .trace import (
+    CheckTracer,
     JsonlSink,
     RunScopedTracer,
     Tracer,
     chrome_trace,
     chrome_trace_from_jsonl,
-    device_annotation,
-    device_step_annotation,
     get_tracer,
     instant,
     span,
@@ -81,6 +81,7 @@ def __getattr__(name):
 
 __all__ = [
     "BlockInstruments",
+    "CheckTracer",
     "CommsInstruments",
     "Counter",
     "CoverageLedger",
@@ -103,8 +104,6 @@ __all__ = [
     "WaveInstruments",
     "chrome_trace",
     "chrome_trace_from_jsonl",
-    "device_annotation",
-    "device_step_annotation",
     "discard_run_registry",
     "get_tracer",
     "instant",

@@ -60,22 +60,12 @@ engine (ROADMAP item 2): the wall-clock a perfect overlap of the host
 phases (probe/evict/checkpoint) under device compute would save —
 ``min(host_overlappable_s, device_s)`` — and the predicted wall under it.
 
-When ``jax.profiler`` is available and a ``profile_dir`` is set, a
-programmatic capture over the first ``profile_waves`` attributed waves is
-parsed (the Chrome-trace export XLA writes) to split device-busy from
-device-idle *inside* the ``device`` phase — the fence can only see the
-outside of the dispatch.
-
 The clock is injectable (tests drive a fake clock through the classifier
 deterministically); ``time.perf_counter`` is the default.
 """
 
 from __future__ import annotations
 
-import glob
-import gzip
-import json
-import os
 import threading
 import time
 from typing import Dict, List, Optional
@@ -88,7 +78,6 @@ __all__ = [
     "HOST_OVERLAPPABLE_PHASES",
     "PHASES",
     "WaveAttribution",
-    "parse_profile_device_busy",
 ]
 
 # The canonical phase names (call sites may add others; the ledger carries
@@ -197,7 +186,6 @@ class _Wave:
     def __enter__(self) -> "_Wave":
         attr = self._attr
         attr._current = self
-        attr._maybe_profile_start()
         self._span = attr._tracer.span(
             f"{attr.prefix}.pipeline", kind=self.kind
         )
@@ -237,7 +225,6 @@ class _Wave:
             **{f"{k}_ms": v * 1e3 for k, v in self.phases.items()},
         )
         self._span.__exit__(exc_type, exc, tb)
-        attr._maybe_profile_stop()
 
 
 class WaveAttribution:
@@ -253,8 +240,6 @@ class WaveAttribution:
         tracer: Tracer = None,
         registry: MetricsRegistry = None,
         tolerance: float = DEFAULT_TOLERANCE,
-        profile_dir: Optional[str] = None,
-        profile_waves: int = 8,
     ):
         self.prefix = prefix
         self._clock = clock if clock is not None else time.perf_counter
@@ -300,12 +285,6 @@ class WaveAttribution:
         # run end from the final table).
         self._probe_hist = reg.histogram(f"{prefix}.hashset.probe_length")
         self._probe_counts: Optional[List[int]] = None
-        # jax.profiler window (best effort, never fatal).
-        self._profile_dir = profile_dir
-        self._profile_waves = max(1, profile_waves)
-        self._profile_state = "pending" if profile_dir else "off"
-        self._profile_t0_waves = 0
-        self.device_split: Optional[Dict[str, float]] = None
 
     # -- recording ---------------------------------------------------------
 
@@ -382,15 +361,13 @@ class WaveAttribution:
         the wave closes normally, so the dying wave's ``.pipeline`` span
         still reaches the trace sinks (flight-recorder forensics) and no
         dangling ``_current``/``_open_phase`` state survives into a
-        later ledger read. Also stops a still-running profiler window.
-        No-op when nothing is open."""
+        later ledger read. No-op when nothing is open."""
         phase = self._open_phase
         if phase is not None:
             phase.__exit__(None, None, None)
         cur = self._current
         if cur is not None:
             cur.__exit__(None, None, None)
-        self._profile_finalize()
 
     def observe_probe_lengths(self, counts) -> None:
         """Feeds the device hash set's displacement counts (index =
@@ -405,51 +382,11 @@ class WaveAttribution:
             if c:
                 self._probe_hist.observe_many(d, c)
 
-    # -- jax.profiler window (device-busy split) ---------------------------
-
-    def _maybe_profile_start(self) -> None:
-        if self._profile_state != "pending":
-            return
-        try:
-            import jax
-
-            jax.profiler.start_trace(self._profile_dir)
-            self._profile_state = "running"
-            self._profile_t0_waves = self._waves + self._drains
-        except Exception:  # noqa: BLE001 - profiler optional by design
-            self._profile_state = "failed"
-
-    def _maybe_profile_stop(self) -> None:
-        if self._profile_state != "running":
-            return
-        done = (self._waves + self._drains) - self._profile_t0_waves
-        if done < self._profile_waves:
-            return
-        self._profile_finalize()
-
-    def _profile_finalize(self) -> None:
-        """Stops a still-running profiler window and parses the capture.
-        Called from the window-count stop, from ``report()`` (a run that
-        finishes in fewer than ``profile_waves`` windows must not leave
-        the process profiler running — a later ``start_trace`` would
-        raise — nor its capture unwritten), and from ``abort()``."""
-        if self._profile_state != "running":
-            return
-        try:
-            import jax
-
-            jax.profiler.stop_trace()
-            self._profile_state = "done"
-            self.device_split = parse_profile_device_busy(self._profile_dir)
-        except Exception:  # noqa: BLE001
-            self._profile_state = "failed"
-
     # -- the ledger ---------------------------------------------------------
 
     def report(self) -> Dict[str, object]:
         """The phase ledger: totals, shares, the sum-to-wall invariant,
         and the overlap-headroom estimate (ROADMAP item 2's go/no-go)."""
-        self._profile_finalize()
         wall = self._wall_s
         phases = {k: v for k, v in sorted(self._totals.items())}
         device = sum(phases.get(p, 0.0) for p in DEVICE_PHASES)
@@ -484,7 +421,6 @@ class WaveAttribution:
                 "headroom_pct": (headroom / wall) if wall else 0.0,
                 "predicted_wall_s": wall - headroom,
             },
-            "device_split": self.device_split,
             # Overlapped execution (async pipelined engine): host time
             # shadowed under device compute — NOT in phases_s, so the
             # sum-to-wall invariant above stays exact in both modes.
@@ -503,55 +439,3 @@ class WaveAttribution:
         if self._probe_counts is not None:
             out["probe_length_counts"] = list(self._probe_counts)
         return out
-
-
-def parse_profile_device_busy(logdir) -> Optional[Dict[str, float]]:
-    """Best-effort device-busy/idle split from a ``jax.profiler`` capture:
-    finds the newest Chrome-trace export under ``logdir`` and sums the
-    complete-event durations on device-named process tracks against the
-    tracks' observed span. Returns ``{"busy_s", "idle_s", "span_s",
-    "source"}`` or None when no device track exists (CPU-only runs) or
-    the capture is unreadable. Overlapping device events are summed, not
-    unioned — an approximation, documented as such."""
-    try:
-        paths = sorted(
-            glob.glob(
-                os.path.join(logdir, "**", "*.trace.json.gz"),
-                recursive=True,
-            ),
-            key=os.path.getmtime,
-        )
-        if not paths:
-            return None
-        with gzip.open(paths[-1], "rt") as f:
-            trace = json.load(f)
-        events = trace.get("traceEvents", [])
-        device_pids = set()
-        for ev in events:
-            if ev.get("ph") == "M" and ev.get("name") == "process_name":
-                pname = (ev.get("args") or {}).get("name", "")
-                if "/device:" in pname or pname.startswith("TPU"):
-                    device_pids.add(ev.get("pid"))
-        if not device_pids:
-            return None
-        busy_us = 0.0
-        t_lo, t_hi = None, None
-        for ev in events:
-            if ev.get("ph") != "X" or ev.get("pid") not in device_pids:
-                continue
-            ts = float(ev.get("ts", 0.0))
-            dur = float(ev.get("dur", 0.0))
-            busy_us += dur
-            t_lo = ts if t_lo is None else min(t_lo, ts)
-            t_hi = ts + dur if t_hi is None else max(t_hi, ts + dur)
-        if t_lo is None:
-            return None
-        span_us = t_hi - t_lo
-        return {
-            "busy_s": busy_us / 1e6,
-            "idle_s": max(0.0, span_us - busy_us) / 1e6,
-            "span_s": span_us / 1e6,
-            "source": "jax.profiler",
-        }
-    except Exception:  # noqa: BLE001 - profiling is advisory, never fatal
-        return None

@@ -13,17 +13,23 @@ Per-state recording is a design error — backends emit one span per
 wave/block/drain, keeping overhead well under the always-on budget
 (asserted by ``tests/test_telemetry.py``'s overhead micro-benchmark).
 
-``device_annotation``/``device_step_annotation`` bridge host spans into
-``jax.profiler`` annotations so they line up with XLA device traces in
-TensorBoard/Perfetto; they degrade to no-ops when jax (or its profiler)
-is unavailable, keeping this module importable everywhere.
+Every span records its own ``id`` and the ``parent`` that caused it: the
+span open on the same thread (a per-thread stack), or, for a thread's
+first span, the root a :class:`CheckTracer` view gives it (a checker's
+``tpu_bfs.check`` span), so spans of one check form one tree across the
+checker's worker thread and its caller's. Every span is also bridged
+into a ``jax.profiler.TraceAnnotation`` of the same name, so a profiler
+capture holds the program's spans on the device trace's clock; the
+bridge needs jax already imported, a disabled tracer opens none, and a
+span backdated with ``since`` keeps none.
 """
 
 from __future__ import annotations
 
-import contextlib
+import itertools
 import json
 import os
+import sys
 import threading
 import time
 import weakref
@@ -31,6 +37,11 @@ from collections import deque
 from typing import Dict, IO, Iterable, List, Optional
 
 RING_CAPACITY = 4096
+
+# Span ids and check ids: process-wide sequences (``next`` on a count is
+# atomic under the interpreter lock).
+_span_ids = itertools.count(1)
+_check_ids = itertools.count(1)
 
 
 def _now_us() -> float:
@@ -88,28 +99,74 @@ class JsonlSink:
         self._finalizer()  # at most once; later calls are no-ops
 
 
+def _annotation(name: str):
+    """An entered ``jax.profiler.TraceAnnotation`` for ``name``, or None
+    while jax is not imported (a process that never imported jax has no
+    profiler capture to line up with)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    try:
+        anno = jax.profiler.TraceAnnotation(name)
+    except AttributeError:  # jax still importing on another thread
+        return None
+    anno.__enter__()
+    return anno
+
+
 class _Span:
     """Context manager for one complete ("X") event. ``args`` is mutable
     until exit — callers fill in quantities only known at span end (a
-    wave's new-unique count, dedup rate, occupancy)."""
+    wave's new-unique count, dedup rate, occupancy). ``id`` is set on
+    enter; ``parent`` is the span open on this thread then, else
+    ``root``."""
 
-    __slots__ = ("_tracer", "name", "args", "_t0")
+    __slots__ = ("_tracer", "name", "args", "_t0", "id", "parent", "_anno")
 
-    def __init__(self, tracer: "Tracer", name: str, args: Dict):
+    def __init__(self, tracer: "Tracer", name: str, args: Dict,
+                 root: Optional[int] = None):
         self._tracer = tracer
         self.name = name
         self.args = args
+        self.id = None
+        self.parent = root
 
     def set(self, **kwargs) -> "_Span":
         self.args.update(kwargs)
         return self
 
+    def since(self, t0_us: float) -> "_Span":
+        """Backdates the open span to ``t0_us`` (an earlier ``_now_us()``),
+        for work known to deserve a span only once it has run. The
+        profiler cannot annotate the past, so the span's annotation is
+        closed here."""
+        self._t0 = t0_us
+        if self._anno is not None:
+            self._anno.__exit__(None, None, None)
+            self._anno = None
+        return self
+
     def __enter__(self) -> "_Span":
+        stack = self._tracer._stack()
+        if stack:
+            self.parent = stack[-1]
+        self.id = next(_span_ids)
+        stack.append(self.id)
+        self._anno = _annotation(self.name)
         self._t0 = _now_us()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         t1 = _now_us()
+        if self._anno is not None:
+            self._anno.__exit__(None, None, None)
+        stack = self._tracer._stack()
+        if stack and stack[-1] == self.id:
+            stack.pop()
+        elif self.id in stack:
+            # Closed out of order (a span that outlives one opened after
+            # it, as a drain exit does an attribution window).
+            stack.remove(self.id)
         self._tracer._emit(
             {
                 "name": self.name,
@@ -118,6 +175,8 @@ class _Span:
                 "dur": t1 - self._t0,
                 "pid": os.getpid(),
                 "tid": threading.get_ident(),
+                "id": self.id,
+                "parent": self.parent,
                 "args": self.args,
             }
         )
@@ -128,11 +187,15 @@ class _NullSpan:
     surface so call sites stay unconditional."""
 
     __slots__ = ("args",)
+    id = None
 
     def __init__(self):
         self.args: Dict = {}
 
     def set(self, **kwargs) -> "_NullSpan":
+        return self
+
+    def since(self, t0_us: float) -> "_NullSpan":
         return self
 
     def __enter__(self) -> "_NullSpan":
@@ -150,6 +213,8 @@ class Tracer:
         self._ring: deque = deque(maxlen=ring_capacity)
         self._sinks: List[JsonlSink] = []
         self.enabled = True
+        # Per-thread stack of open span ids: a new span's parent.
+        self._local = threading.local()
         # Emit lock: the async wave engine's host worker closes wave
         # spans concurrently with the checker thread's drain/compile
         # spans (and the monitor's tracer-sink tap consumes both), so
@@ -166,9 +231,23 @@ class Tracer:
         """``with tracer.span("tpu_bfs.wave", frontier=F) as sp: ...`` —
         the span records begin/duration on exit; fill late-bound args via
         ``sp.set(...)`` or ``sp.args[...]``."""
+        return self._open(name, args, None)
+
+    def _open(self, name: str, args: Dict, root: Optional[int]):
         if not self.enabled:
             return _NULL_SPAN
-        return _Span(self, name, args)
+        return _Span(self, name, args, root)
+
+    def _stack(self) -> List[int]:
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
+    def current_span(self) -> Optional[int]:
+        """The id of the innermost span open on this thread, or None."""
+        stack = self._stack()
+        return stack[-1] if stack else None
 
     def instant(self, name: str, **args) -> None:
         """A point event (scope: thread)."""
@@ -243,22 +322,46 @@ class RunScopedTracer:
     attributable — ``MonitorCore(run_filter=...)`` selects one run's
     stream, and trace readers can group by ``args.run_id``. Everything
     else (sinks, ring buffer, enablement) delegates to the wrapped
-    tracer: the events still land in THE process-local stream."""
+    tracer: the events still land in THE process-local stream.
+    ``root`` parents the first span a thread opens through this view."""
 
     def __init__(self, run_id: str, tracer: Optional[Tracer] = None):
         self.run_id = run_id
         self._tracer = tracer if tracer is not None else get_tracer()
+        self._stamp = {"run_id": run_id}
+        self.root: Optional[int] = None
 
     def span(self, name: str, **args):
-        args.setdefault("run_id", self.run_id)
-        return self._tracer.span(name, **args)
+        for k, v in self._stamp.items():
+            args.setdefault(k, v)
+        return self._tracer._open(name, args, self.root)
 
     def instant(self, name: str, **args) -> None:
-        args.setdefault("run_id", self.run_id)
+        for k, v in self._stamp.items():
+            args.setdefault(k, v)
         self._tracer.instant(name, **args)
 
     def __getattr__(self, name):
         return getattr(self._tracer, name)
+
+
+class CheckTracer(RunScopedTracer):
+    """The view one check records through: every span and instant
+    carries ``check`` (the ``run_id`` when given, else the next number
+    of a per-process sequence) and, with a ``run_id``, ``run_id`` too.
+    ``root`` starts as the span open on the spawning thread; the checker
+    sets it to its ``tpu_bfs.check`` span once that opens, so the spans
+    its caller's thread records for it (``discoveries()``) join the
+    check's tree."""
+
+    def __init__(self, run_id: Optional[str] = None,
+                 tracer: Optional[Tracer] = None):
+        super().__init__(run_id, tracer)
+        self.check = run_id if run_id is not None else next(_check_ids)
+        self._stamp = {"check": self.check}
+        if run_id is not None:
+            self._stamp["run_id"] = run_id
+        self.root = self._tracer.current_span()
 
 
 _default_tracer = Tracer()
@@ -315,28 +418,3 @@ def chrome_trace_from_jsonl(path) -> Dict:
 def write_chrome_trace(path, events: Optional[Iterable[Dict]] = None) -> None:
     with open(path, "w") as f:
         json.dump(chrome_trace(events), f)
-
-
-# -- jax.profiler bridge ---------------------------------------------------
-
-
-def device_annotation(name: str):
-    """A ``jax.profiler.TraceAnnotation`` so the host span shows up in
-    XLA device traces; a no-op context when jax is unavailable."""
-    try:
-        import jax
-
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # noqa: BLE001 - profiler optional by design
-        return contextlib.nullcontext()
-
-
-def device_step_annotation(name: str, step: int):
-    """A ``jax.profiler.StepTraceAnnotation`` (step-aligned variant used
-    by the per-wave/per-drain loops); no-op without jax."""
-    try:
-        import jax
-
-        return jax.profiler.StepTraceAnnotation(name, step_num=step)
-    except Exception:  # noqa: BLE001
-        return contextlib.nullcontext()

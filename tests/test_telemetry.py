@@ -7,6 +7,7 @@ import json
 import os
 import subprocess
 import sys
+import threading
 import time
 
 import pytest
@@ -14,6 +15,7 @@ import pytest
 from fixtures import LinearEquation
 from stateright_tpu import TelemetryReporter, WriteReporter, fingerprint
 from stateright_tpu.telemetry import (
+    CheckTracer,
     Counter,
     Gauge,
     Histogram,
@@ -121,6 +123,102 @@ def test_disabled_tracer_emits_nothing():
         sp.set(a=1)
     tracer.instant("tick")
     assert tracer.events() == []
+
+
+def test_spans_record_parent_and_check_across_threads():
+    tracer = Tracer()
+    view = CheckTracer(tracer=tracer)
+    other = CheckTracer("job-7", tracer=tracer)
+    assert other.check == "job-7" and view.check != other.check
+
+    def worker():
+        with view.span("remote"):
+            with view.span("leaf"):
+                pass
+
+    with view.span("root") as root:
+        view.root = root.id
+        with view.span("child"):
+            thread = threading.Thread(target=worker)
+            thread.start()
+            thread.join(timeout=30)
+        assert not thread.is_alive()
+    with other.span("job"):
+        tracer.instant("tick")
+    ev = {e["name"]: e for e in tracer.events()}
+    assert ev["root"]["parent"] is None
+    assert ev["child"]["parent"] == ev["root"]["id"]
+    # A thread's first span hangs off the view's root, not the span that
+    # happens to be open on the spawning thread.
+    assert ev["remote"]["parent"] == ev["root"]["id"]
+    assert ev["leaf"]["parent"] == ev["remote"]["id"]
+    assert ev["remote"]["tid"] != ev["root"]["tid"]
+    ours = ("root", "child", "remote", "leaf")
+    assert len({ev[n]["id"] for n in ours}) == 4
+    assert {ev[n]["args"]["check"] for n in ours} == {view.check}
+    assert "run_id" not in ev["root"]["args"]
+    assert ev["job"]["args"] == {"check": "job-7", "run_id": "job-7"}
+    # Nesting on the plain tracer: the instant is no span; the plain
+    # tracer stamps no check.
+    assert ev["job"]["parent"] is None and ev["tick"]["args"] == {}
+
+
+def test_profiler_bridge_follows_enablement(monkeypatch):
+    import jax
+
+    opened = []
+
+    class Annotation:
+        def __init__(self, name):
+            opened.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    tracer = Tracer()
+    with tracer.span("on"):
+        pass
+    assert opened == ["on"]
+    tracer.enabled = False
+    with tracer.span("off"):
+        pass
+    with CheckTracer(tracer=tracer).span("off.view"):
+        pass
+    assert opened == ["on"]
+
+
+def test_traced_jit_records_only_calls_that_obtain_an_executable():
+    import jax
+    import jax.numpy as jnp
+
+    from stateright_tpu.telemetry.executables import TracedJit
+
+    tracer = Tracer()
+    fn = TracedJit(jax.jit(lambda x: x + 1), tracer, "t.exe", "inc")
+    with tracer.span("outer") as outer:
+        t0 = time.perf_counter_ns() / 1e3
+        fn(jnp.zeros(3))
+        t1 = time.perf_counter_ns() / 1e3
+        fn(jnp.ones(3))  # same signature: reused, no span
+        fn(jnp.zeros(5))  # new shape: a second acquisition
+    exes = [e for e in tracer.events() if e["name"] == "t.exe"]
+    assert len(exes) == 2
+    first = exes[0]
+    # Backdated to the call's start, inside the span open around it.
+    assert t0 <= first["ts"] and first["ts"] + first["dur"] <= t1
+    assert first["parent"] == outer.id
+    assert first["args"]["kind"] == "inc"
+    assert first["args"]["source"] in ("memory", "persistent_cache", "compile")
+    assert first["args"]["trace_ms"] >= 0 and first["args"]["build_ms"] >= 0
+    tracer.enabled = False
+    fn(jnp.zeros(7))
+    tracer.enabled = True
+    fn(jnp.zeros(7))  # obtained while disabled: held, so no span now
+    assert len([e for e in tracer.events() if e["name"] == "t.exe"]) == 2
 
 
 def test_jsonl_sink_and_chrome_export(tmp_path):
