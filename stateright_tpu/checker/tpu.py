@@ -57,8 +57,10 @@ from ..ops.fingerprint import (
     fp_to_int,
 )
 from ..ops.hashset import (
+    INSERT_COUNTERS,
     hashset_insert,
     hashset_insert_unsorted,
+    insert_counted,
     hashset_new,
 )
 from ..ops.ring import ring_export, ring_push, ring_rows, ring_take
@@ -436,6 +438,12 @@ def default_wave_dedup(platform: str, hashset_impl: str = "xla") -> str:
     if hashset_impl == "pallas" or platform != "cpu":
         return "sort"
     return "scatter"
+
+
+def _insert_args(counts):
+    """Span args of the visited-set insert's counters (a float32 vector of
+    ``INSERT_COUNTERS``), as ``insert_<counter>``: whole numbers."""
+    return {f"insert_{k}": int(v) for k, v in zip(INSERT_COUNTERS, counts)}
 
 
 def _pow2ceil(n: int) -> int:
@@ -1122,15 +1130,17 @@ class TpuBfsChecker(Checker):
         """Visited-set insert for a wave batch (keys sorted ascending —
         both impls rely on it: XLA for first-claim-wins tie order, Pallas
         for its single left-to-right table sweep). Off-TPU the Pallas
-        kernel runs in interpret mode: exact semantics, testing speed only."""
+        kernel runs in interpret mode: exact semantics, testing speed only.
+        Returns ``hashset_insert``'s four outputs and the XLA insert's
+        counter vector (``INSERT_COUNTERS``; None for Pallas)."""
         if self._hashset_impl == "pallas":
             from ..ops.pallas_hashset import pallas_hashset_insert
 
-            return pallas_hashset_insert(
+            return (*pallas_hashset_insert(
                 table, shi, slo, active,
                 interpret=jax.default_backend() != "tpu",
-            )
-        return hashset_insert(table, shi, slo, active)
+            ), None)
+        return insert_counted(table, shi, slo, active)
 
     def _init_wave(self, table):
         states = self._model.packed_init_states()
@@ -1150,7 +1160,7 @@ class TpuBfsChecker(Checker):
             [jnp.ones((1,), bool), (shi[1:] != shi[:-1]) | (slo[1:] != slo[:-1])]
         )
         wave_unique = valid[sidx] & uniq
-        table, fresh, _found, pending = self._insert_sorted(
+        table, fresh, _found, pending, _counts = self._insert_sorted(
             table, shi, slo, wave_unique
         )
         out = {
@@ -1268,6 +1278,7 @@ class TpuBfsChecker(Checker):
             elog = edge_log_append(
                 elog, live_rows, live_n, self._elog_capacity
             )
+        insert_counts = None
         if self._wave_dedup == "scatter":
             # Sort-free dedup: the duplicate-tolerant insert resolves
             # in-wave twins itself (owner-ticket tie-break), so the
@@ -1298,8 +1309,8 @@ class TpuBfsChecker(Checker):
             # Claim slots in the visited set; fresh lanes form the next
             # frontier.
             with jax.named_scope("tpu_bfs.insert"):
-                table, fresh, _found, pending = self._insert_sorted(
-                    table, shi, slo, wave_unique
+                table, fresh, _found, pending, insert_counts = (
+                    self._insert_sorted(table, shi, slo, wave_unique)
                 )
         with jax.named_scope("tpu_bfs.compact"):
             overflow = pending.sum()
@@ -1432,6 +1443,8 @@ class TpuBfsChecker(Checker):
         ]
         if self._properties:
             stats.append(out["prop_hit"].any().astype(jnp.int32))
+        if insert_counts is not None:
+            out["insert"] = insert_counts
         if elog is not None:
             out["elog"] = elog
             # Absolute fill count — the host's pre-dispatch eviction
@@ -1567,9 +1580,25 @@ class TpuBfsChecker(Checker):
                 elog=el,
             )
 
-        with jax.named_scope("tpu_bfs.ring"):
-            frontier0, head, count = self._pool_take(pool, head, count, F)
-        out0 = wave_of(table, frontier0, elog)
+        # The loop holds the one copy of the wave in the program: its first
+        # iteration consumes an empty wave (nothing to log or push) and
+        # runs the drain's first wave, so no wave runs ahead of the loop
+        # (each copy is tens of MB of device code, resident while the
+        # executable is loaded).
+        def zeros(tree):
+            return jax.tree_util.tree_map(
+                lambda x: jnp.zeros(x.shape, x.dtype), tree
+            )
+
+        frontier0 = zeros(
+            jax.eval_shape(
+                lambda *a: self._pool_take(*a, F), pool, head, count
+            )[0]
+        )
+        out0 = zeros(jax.eval_shape(wave_of, table, frontier0, elog))
+        out0["table"] = table
+        if elog is not None:
+            out0["elog"] = elog
         zl = jnp.zeros((L,), jnp.uint32)
         log0 = {
             "child_hi": zl,
@@ -1591,13 +1620,12 @@ class TpuBfsChecker(Checker):
             "consumed_unique": jnp.int32(0),
             "max_depth": jnp.int32(0),
             "budget": budget,
-            # The pre-loop wave (out0) counts against the cap too, so a
-            # drain runs at most max_drain_waves waves total (the cap backs
-            # the checkpoint-durability guarantee).
-            "waves": jnp.int32(1),
+            # Waves run (the cap backs the checkpoint-durability
+            # guarantee: a drain runs at most max_drain_waves waves).
+            "waves": jnp.int32(0),
             # Live lanes dispatched (the drain's compaction-ratio
             # numerator; the denominator is waves × width, host-side).
-            "live_sum": frontier0["mask"].sum(dtype=jnp.int32),
+            "live_sum": jnp.int32(0),
         }
         if self._cov is not None:
             # Consumed waves' coverage vectors accumulate in the carry
@@ -1606,11 +1634,16 @@ class TpuBfsChecker(Checker):
             carry["cov_acc"] = jnp.zeros(
                 (self._cov_layout.size,), jnp.int32
             )
+        if "insert" in out0:
+            # The consumed waves' insert counters, likewise.
+            carry["insert_acc"] = jnp.zeros_like(out0["insert"])
 
         def cond(c):
             o = c["out"]
             n_new = o["n_new"]
             ok = (n_new > 0) | (c["count"] > 0)
+            # The first iteration always runs: it is the drain's first wave.
+            first = c["waves"] == 0
             ok &= o["overflow"] == 0
             if P:
                 ok &= ~(o["prop_hit"] & undiscovered).any()
@@ -1634,7 +1667,7 @@ class TpuBfsChecker(Checker):
             # host (which accumulates in a Python int) long before a
             # billion-generated drain could wrap it.
             ok &= c["generated"] < jnp.int32(1 << 30)
-            return ok
+            return ok | first
 
         def body(c):
             o = c["out"]
@@ -1716,6 +1749,8 @@ class TpuBfsChecker(Checker):
             }
             if self._cov is not None:
                 nxt["cov_acc"] = c["cov_acc"] + o["cov"]
+            if "insert_acc" in c:
+                nxt["insert_acc"] = c["insert_acc"] + o["insert"]
             return nxt
 
         res = jax.lax.while_loop(cond, body, carry)
@@ -1733,6 +1768,13 @@ class TpuBfsChecker(Checker):
                 res["live_sum"],
             ]
         )
+        if "insert_acc" in res:
+            # The float32 insert counters ride the same transfer, bit for
+            # bit (the host views entries 7.. as float32).
+            res["drain_stats"] = jnp.concatenate([
+                res["drain_stats"],
+                jax.lax.bitcast_convert_type(res["insert_acc"], jnp.int32),
+            ])
         cols = ["child_hi", "child_lo", "parent_hi", "parent_lo"]
         if self._symmetry_enabled:
             cols += ["key_hi", "key_lo"]
@@ -2168,6 +2210,7 @@ class TpuBfsChecker(Checker):
         generated = 0
         wave_new = 0
         stale_total = 0
+        insert_counts = 0
         self._last_dispatch = None
         while True:
             if wave is None:
@@ -2175,6 +2218,9 @@ class TpuBfsChecker(Checker):
                 # wave's parent references index into ITS lanes.
                 wave, chunk = self._call_wave(table, chunk, depth_cap)
             table = wave["table"]
+            if "insert" in wave:
+                # Every attempt's probe rounds were issued: they all count.
+                insert_counts = insert_counts + np.asarray(wave["insert"])
             # Single host transfer per wave: [generated, n_new, overflow,
             # max_depth, any_prop_hit?, edge_count?]; per-property
             # fingerprints are pulled only on a hit.
@@ -2213,6 +2259,8 @@ class TpuBfsChecker(Checker):
                     span, chunk["hi"].shape[0], generated, wave_new,
                     stale=stale_total, pending=pending,
                 )
+                if span is not None and "insert" in wave:
+                    span.set(**_insert_args(insert_counts))
                 if self._cov is not None:
                     self._cov.emit_wave_span()
                 return table, wave_new
@@ -2833,6 +2881,10 @@ class TpuBfsChecker(Checker):
                         bucket=width,
                         compaction_ratio=compaction,
                     )
+                    if len(dstats) > 7:
+                        drain_span.set(**_insert_args(dstats[7:11].view(
+                            np.float32
+                        )))
                 # The drain's result is in (its stats pull above waited
                 # for the device): host time until the next dispatch.
                 self._drain_exit = self._tracer.span(

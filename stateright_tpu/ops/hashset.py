@@ -23,12 +23,24 @@ Keys must be wave-unique before insertion (dedup by sort upstream) so a
 already present from an earlier wave — the two outcomes the checker needs to
 distinguish are disambiguated by ``fresh`` (claim won) vs ``found``.
 
+A probe round's gathers and claim scatter cost per lane issued, masked or
+not, so the sorted insert probes at a width that follows its pending lanes:
+a static ladder of widths from the batch width down by eighths (rounded up
+to 128 lanes; a batch of 1,024 lanes or fewer keeps one width). Before a round,
+once every pending lane fits the next width, the pending lanes move there in
+their order (an order-preserving sort on "not pending"), keep their key and
+home, and go on probing at ``home + r``. A lane is never left pending by the
+width, so the fresh set, the found set, the resident key set and the overflow
+lanes are those of a single-width loop; only the slot a tied claim lands in
+may differ, as it may between any two runs of the claim scatter.
+
 The all-zero pair is the empty sentinel (fingerprints are never (0, 0) —
 see ``ops.fingerprint``).
 """
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Tuple
 
 import jax
@@ -39,6 +51,9 @@ __all__ = [
     "hashset_insert",
     "hashset_insert_unsorted",
     "hashset_insert_salted",
+    "insert_counted",
+    "probe_widths",
+    "INSERT_COUNTERS",
     "hashset_contains",
     "hashset_probe_length_counts",
     "MAX_PROBES",
@@ -79,40 +94,136 @@ def hashset_insert(
     - ``overflow``: probe cap exhausted (host must grow and retry).
     Inactive lanes report none of the three.
     """
+    table, fresh, found, pending, _counts = insert_counted(
+        table, key_hi, key_lo, active
+    )
+    return table, fresh, found, pending
+
+
+# Fields of ``insert_counted``'s counter vector: float32, so that a sum over
+# a long drain cannot wrap as int32 would.
+INSERT_COUNTERS = ("rounds", "lanes_issued", "lanes_live", "narrowings")
+
+
+def probe_widths(n: int) -> Tuple[int, ...]:
+    """The static ladder of probe widths for a batch of ``n`` lanes: ``n``,
+    then an eighth of the previous width rounded up to a multiple of 128,
+    while the previous width exceeds 1,024 lanes. Each width is a probe
+    loop and a sort in every program that inserts, megabytes of device
+    code at the widest, so the ladder steps by eighths, not quarters."""
+    widths = [n]
+    while widths[-1] > 1024:
+        widths.append(-(-widths[-1] // 1024) * 128)
+    return tuple(widths)
+
+
+def insert_counted(
+    table: jax.Array,
+    key_hi: jax.Array,
+    key_lo: jax.Array,
+    active: jax.Array,
+) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
+    """``hashset_insert`` plus its counters: returns ``(table, fresh, found,
+    overflow, counts)`` with ``counts`` a float32 vector of
+    ``INSERT_COUNTERS``: probe rounds, lanes issued (each round's width),
+    live lanes (pending at each round's start) and narrowings.
+
+    Each width of ``probe_widths`` is one ``while_loop`` over the same round
+    body, run until the pending lanes fit the next width. The table is
+    carried by those loops alone (a ``lax.cond`` only skips a narrowing's
+    sort when nothing is left to probe), so it stays in place. Outcomes come
+    back to the original lanes by one gather per narrowing, through each
+    pending lane's position among the pending."""
     capacity = table.shape[0] - MAX_PROBES
-    base = _home(key_hi, capacity)
+    oob = capacity + MAX_PROBES
+    widths = probe_widths(key_hi.shape[0])
 
-    def cond(carry):
-        _table, r, pending, _fresh, _found = carry
-        return (r < MAX_PROBES) & pending.any()
-
-    def body(carry):
-        table, r, pending, fresh, found = carry
+    def probe_round(table, r, khi, klo, base, pending):
         idx = base + r
         row = table[idx]
-        cur_hi, cur_lo = row[:, 0], row[:, 1]
-        empty = (cur_hi == 0) & (cur_lo == 0)
-        match = (cur_hi == key_hi) & (cur_lo == key_lo)
-        found = found | (pending & match)
+        empty = (row[:, 0] == 0) & (row[:, 1] == 0)
+        match = (row[:, 0] == khi) & (row[:, 1] == klo)
         attempt = pending & empty & ~match
         # Claim: one full-row update wins per index; losers observe the
         # winner's key on re-read and continue probing. (OOB sentinel must
         # lie past the apron — ``capacity`` itself is a valid apron slot.)
-        scatter_idx = jnp.where(attempt, idx, capacity + MAX_PROBES)
-        update = jnp.stack([key_hi, key_lo], axis=-1)
+        scatter_idx = jnp.where(attempt, idx, oob)
+        update = jnp.stack([khi, klo], axis=-1)
         table = table.at[scatter_idx].set(update, mode="drop")
         row2 = table[idx]
-        won = attempt & (row2[:, 0] == key_hi) & (row2[:, 1] == key_lo)
-        fresh = fresh | won
-        pending = pending & ~match & ~won
-        return table, r + 1, pending, fresh, found
+        won = attempt & (row2[:, 0] == khi) & (row2[:, 1] == klo)
+        return table, pending & ~match & ~won, won, pending & match
 
-    n = key_hi.shape[0]
-    falses = jnp.zeros((n,), dtype=bool)
-    table, _r, pending, fresh, found = jax.lax.while_loop(
-        cond, body, (table, jnp.int32(0), active, falses, falses)
-    )
-    return table, fresh, found, pending
+    def probe_stage(table, r, khi, klo, pending, npend, counts, limit):
+        w = khi.shape[0]
+        base = _home(khi, capacity)
+
+        def cond(c):
+            return (c[1] < MAX_PROBES) & (c[6] > limit)
+
+        def body(c):
+            table, r, pending, fresh, found, counts, npend = c
+            counts = counts + jnp.stack([
+                jnp.float32(1), jnp.float32(w), npend.astype(jnp.float32),
+                jnp.float32(0),
+            ])
+            table, pending, won, match = probe_round(
+                table, r, khi, klo, base, pending
+            )
+            return (table, r + 1, pending, fresh | won, found | match,
+                    counts, pending.sum(dtype=jnp.int32))
+
+        falses = jnp.zeros((w,), bool)
+        return jax.lax.while_loop(
+            cond, body, (table, r, pending, falses, falses, counts, npend)
+        )
+
+    def pending_first(pending, w2):
+        """The first ``w2`` lanes of an order-preserving sort on "not
+        pending": every pending lane, in lane order, then filler."""
+        lane = jnp.arange(pending.shape[0], dtype=jnp.uint32)
+        order = jax.lax.sort(
+            jnp.where(pending, lane, lane | jnp.uint32(1 << 31))
+        )[:w2]
+        return (
+            (order & jnp.uint32((1 << 31) - 1)).astype(jnp.int32),
+            order < jnp.uint32(1 << 31),
+        )
+
+    def nothing(_pending, w2):
+        return jnp.zeros((w2,), jnp.int32), jnp.zeros((w2,), bool)
+
+    r = jnp.int32(0)
+    khi, klo, pending = key_hi, key_lo, active
+    npend = active.sum(dtype=jnp.int32)
+    counts = jnp.zeros((4,), jnp.float32)
+    narrowed = []
+    for w2 in widths[1:] + (0,):
+        table, r, pending, fresh, found, counts, npend = probe_stage(
+            table, r, khi, klo, pending, npend, counts, w2
+        )
+        if not w2:
+            break
+        go = (npend > 0) & (r < MAX_PROBES)
+        j, keep = jax.lax.cond(
+            go, partial(pending_first, w2=w2), partial(nothing, w2=w2),
+            pending,
+        )
+        narrowed.append((fresh, found, pending, pending & go, w2))
+        counts = counts + jnp.where(
+            go, jnp.array([0.0, 0.0, 0.0, 1.0], jnp.float32), 0.0
+        )
+        khi, klo, pending = khi[j], klo[j], keep
+    # A lane still pending when the probes ran out was not moved on.
+    for fresh0, found0, pending0, moved, w2 in reversed(narrowed):
+        code = (fresh.astype(jnp.int32) | (found.astype(jnp.int32) << 1)
+                | (pending.astype(jnp.int32) << 2))
+        slot = jnp.where(moved, jnp.cumsum(moved.astype(jnp.int32)) - 1, w2)
+        back = jnp.take(code, slot, mode="fill", fill_value=0)
+        fresh = fresh0 | ((back & 1) != 0)
+        found = found0 | ((back & 2) != 0)
+        pending = jnp.where(moved, (back & 4) != 0, pending0)
+    return table, fresh, found, pending, counts
 
 
 def hashset_insert_unsorted(

@@ -160,6 +160,45 @@ def test_paths_count_log_entries_and_fingerprint_calls(two_checks):
         assert paths["args"]["log_entries"] <= checker.unique_state_count()
 
 
+INSERT_ARGS = (
+    "insert_rounds",
+    "insert_lanes_issued",
+    "insert_lanes_live",
+    "insert_narrowings",
+)
+
+
+def test_drains_and_waves_count_the_visited_set_insert():
+    """2pc-5 on 256-lane waves: each drain, and each drain's last wave,
+    carries the insert's probe rounds, lanes issued and live, and
+    narrowings; the batch narrows, so fewer lanes are issued than rounds at
+    the full batch width."""
+    rec = _Recorder()
+    get_tracer().add_sink(rec)
+    try:
+        checker = TwoPhaseSys(5).checker().spawn_tpu_bfs(
+            frontier_capacity=256, table_capacity=1 << 12, wave_dedup="sort",
+        ).join()
+    finally:
+        get_tracer().remove_sink(rec, close=False)
+    assert checker.unique_state_count() == 8832
+    assert checker.state_count() == 58146
+    width = 256 * checker._A
+    totals = dict.fromkeys(INSERT_ARGS, 0)
+    for name in ("tpu_bfs.drain", "tpu_bfs.wave"):
+        spans = _spans(rec.events, name)
+        assert spans, name
+        for e in spans:
+            a = e["args"]
+            assert set(INSERT_ARGS) <= set(a), (name, a)
+            assert 0 <= a["insert_lanes_live"] <= a["insert_lanes_issued"]
+            assert a["insert_lanes_issued"] <= a["insert_rounds"] * width
+            for k in INSERT_ARGS:
+                totals[k] += a[k]
+    assert totals["insert_rounds"] > 0 and totals["insert_narrowings"] > 0
+    assert totals["insert_lanes_issued"] < totals["insert_rounds"] * width
+
+
 def test_drain_program_names_its_stages(two_checks):
     checker, _ = two_checks[0]
     width = checker._F_max
