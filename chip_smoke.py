@@ -5,7 +5,8 @@
     python chip_smoke.py --chips 4    # four chips: sharded 2pc check 7 only
 
 Each phase runs a model to completion through the public API
-(``checker().spawn_tpu_bfs(...)`` / ``spawn_sharded_tpu_bfs(...)``) with
+(``checker().spawn_tpu_bfs(...)``, with ``devices=4`` for the sharded
+checker over a four-device mesh) with
 the bench legs' own spawn arguments (``bench._leg_specs``), asserts the
 exact unique-state count the leg pins and every property verdict, and
 prints one ``phase {...}`` line: the device, the counts, max depth, wall
@@ -44,15 +45,12 @@ def device_info():
     }
 
 
-def run_phase(name, model, spawn, expected, sharded=False):
+def run_phase(name, model, spawn, expected):
     """Run ``model`` to completion, assert ``expected`` unique states and
     every property, print the phase line, and return the checker."""
     builder = model.checker()
     t0 = time.perf_counter()
-    if sharded:
-        checker = builder.spawn_sharded_tpu_bfs(**spawn).join()
-    else:
-        checker = builder.spawn_tpu_bfs(**spawn).join()
+    checker = builder.spawn_tpu_bfs(**spawn).join()
     wall = time.perf_counter() - t0
     unique = checker.unique_state_count()
     if unique != expected:
@@ -86,19 +84,17 @@ def phase_leg(leg, name):
 
 def phase_sharded_2pc(n_devices):
     from bench import _leg_specs
-    from stateright_tpu.parallel.base_mesh import default_mesh
 
     spec = _leg_specs()["2pc"]
     checker = run_phase(
         f"2pc check 7 sharded over {n_devices} devices",
         spec["model"](),
         dict(
-            mesh=default_mesh(n_devices),
+            devices=n_devices,
             frontier_per_device=SHARDED_FRONTIER_PER_DEVICE,
             table_capacity_per_device=SHARDED_TABLE_PER_DEVICE,
         ),
         spec["expected"],
-        sharded=True,
     )
     placement = checker.visited_placement
     devices = {device.id for device, _shape in placement}

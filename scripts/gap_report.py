@@ -94,22 +94,23 @@ COMMS_KEYS = (
     "comms_bloom_probes",
     "comms_bloom_hits",
     "comms_bloom_fps",
-    "comms_lanes",
-    "comms_bytes",
+    "exchange_lanes",
+    "exchange_bytes",
 )
 
 
 def collect_comms(events):
     """Per-prefix exchange-ledger sums from the wave/drain spans that
-    carry ``comms_*`` args: ``{prefix: {key: total}}``. Empty for
-    single-device traces and for sharded runs whose exchange shipped
-    nothing (a zero-lane trace has no ledger to render)."""
+    carry ``exchange_*`` (and ``comms_*``) args: ``{prefix: {key:
+    total}}``. Empty for single-device traces and for sharded runs whose
+    exchange shipped nothing (a zero-lane trace has no ledger to
+    render)."""
     out = {}
     for ev in events:
         if ev.get("ph") != "X":
             continue
         args = ev.get("args") or {}
-        if "comms_lanes" not in args:
+        if "exchange_lanes" not in args:
             continue
         prefix = ev.get("name", "").rsplit(".", 1)[0]
         led = out.setdefault(prefix, dict.fromkeys(COMMS_KEYS, 0))
@@ -135,8 +136,8 @@ def comms_block(c):
 
 def print_comms(prefix, c, out=sys.stdout):
     out.write(
-        f"comms ledger: {prefix} — {c['comms_lanes']:,} lanes / "
-        f"{c['comms_bytes']:,} bytes shipped cross-shard\n"
+        f"comms ledger: {prefix} — {c['exchange_lanes']:,} lanes / "
+        f"{c['exchange_bytes']:,} bytes shipped cross-shard\n"
     )
     probes, killed = c["comms_probes"], c["comms_killed"]
     if probes:

@@ -119,7 +119,7 @@ class CheckerBuilder:
 
         return SimulationChecker(self, seed, chooser or UniformChooser())
 
-    def spawn_tpu_bfs(self, **kwargs):
+    def spawn_tpu_bfs(self, devices: Optional[int] = None, **kwargs):
         """TPU-accelerated BFS: vmapped frontier expansion + device-resident
         fingerprint set. Requires the model to implement ``BatchableModel``
         (or be convertible via ``stateright_tpu.models.packing``).
@@ -128,10 +128,36 @@ class CheckerBuilder:
         properties, coverage — as one Pallas dispatch per wave instead
         of the staged XLA chain (README "Fused wave megakernel");
         bit-identical to ``wave_kernel="staged"`` with
-        ``wave_dedup="sort"``, interpreted off-TPU."""
-        from .tpu import TpuBfsChecker
+        ``wave_dedup="sort"``, interpreted off-TPU.
 
-        return TpuBfsChecker(self, **kwargs)
+        ``devices=n`` spreads the check over the first ``n`` devices, as
+        upstream's ``threads(n)`` spreads it over workers: the sharded
+        checker (``spawn_sharded_tpu_bfs``) over ``default_mesh(n)``, its
+        visited set partitioned by fingerprint. Its arguments are the
+        sharded checker's (``frontier_per_device``,
+        ``table_capacity_per_device``, ...); one the sharded checker does
+        not take raises ``ValueError`` naming it."""
+        if devices is None:
+            from .tpu import TpuBfsChecker
+
+            return TpuBfsChecker(self, **kwargs)
+        import inspect
+
+        from ..parallel.base_mesh import default_mesh
+        from ..parallel.sharded import ShardedTpuBfsChecker
+
+        taken = inspect.signature(ShardedTpuBfsChecker).parameters
+        refused = sorted(k for k in kwargs if k == "mesh" or k not in taken)
+        if refused:
+            raise ValueError(
+                f"spawn_tpu_bfs(devices={devices}) runs the sharded checker, "
+                f"which takes no {', '.join(refused)} (the mesh comes from "
+                "devices; per-device widths are frontier_per_device and "
+                "table_capacity_per_device)"
+            )
+        return ShardedTpuBfsChecker(
+            self, mesh=default_mesh(devices), **kwargs
+        )
 
     def spawn_sharded_tpu_bfs(self, mesh=None, **kwargs):
         """Multi-device BFS over a ``jax.sharding.Mesh``: the visited set is

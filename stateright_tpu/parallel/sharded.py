@@ -36,6 +36,7 @@ allgather; checkpoints written by process 0). Exercised end-to-end on a
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from collections import deque
@@ -56,11 +57,12 @@ from ..ops.fingerprint import fingerprint_state, fp64_pairs, fp_to_int
 from ..ops.hashset import MAX_PROBES, hashset_insert
 from ..ops.ring import ring_export, ring_push, ring_rows, ring_take
 from ..telemetry import (
+    CheckTracer,
     CommsInstruments,
     WaveInstruments,
-    get_tracer,
     metrics_registry,
 )
+from ..telemetry.executables import exe_span
 from .base_mesh import default_mesh
 from ..checker.base import Checker
 from ..checker.pipeline import HostPipeline
@@ -68,11 +70,14 @@ from ..utils.faults import fault_point
 from ..checker.tpu import (
     _AUTO_BUCKET_MIN_F,
     _DEFAULT_BUCKET_STEPS,
+    CUSTOM_REP_SCHEME,
     _make_key_fn,
     atomic_pickle,
     bucket_for,
     bucket_ladder_widths,
     checkpoint_header,
+    packed_model_digest,
+    shared_aot_cache,
     sym_key_scheme,
     validate_checkpoint_header,
 )
@@ -102,6 +107,31 @@ def _sort_dedup(hi, lo, active):
         [jnp.ones((1,), bool), (shi[1:] != shi[:-1]) | (slo[1:] != slo[:-1])]
     )
     return shi, slo, sidx, active[sidx] & uniq
+
+
+class _ShardedJit:
+    """A program of one sharded checker, run as AOT executables that its
+    ``_obtain`` keys on the program's ``kind``, its first ``static``
+    arguments and the shapes and dtypes of the rest: checks under one
+    ``aot_cache`` namespace share them."""
+
+    __slots__ = ("_checker", "_kind", "_jit", "_static")
+
+    def __init__(self, checker, kind, jit_fn, static=0):
+        self._checker = checker
+        self._kind = kind
+        self._jit = jit_fn
+        self._static = static
+
+    def __call__(self, *args):
+        dyn = args[self._static:]
+        leaves, tree = jax.tree_util.tree_flatten(dyn)
+        key = (
+            args[: self._static],
+            tree,
+            tuple((tuple(x.shape), str(x.dtype)) for x in leaves),
+        )
+        return self._checker._obtain(self._kind, key, self._jit, args)(*dyn)
 
 
 class ShardedTpuBfsChecker(Checker):
@@ -140,6 +170,7 @@ class ShardedTpuBfsChecker(Checker):
         async_pipeline=False,
         liveness=None,
         wave_kernel="staged",
+        aot_cache=None,
         aot_store=None,
         sieve=None,
         sieve_slots_per_device=None,
@@ -175,10 +206,12 @@ class ShardedTpuBfsChecker(Checker):
         if wave_kernel == "fused":
             raise ValueError(self.wave_kernel_reason)
         # Run identity (checking-as-a-service): own metrics registry +
-        # run-stamped trace spans, mirroring TpuBfsChecker.
+        # run-stamped trace spans, mirroring TpuBfsChecker. Every span
+        # carries this check's id and descends from its
+        # ``sharded_bfs.check`` span.
         self.run_id = run_id
         self._registry = metrics_registry(run_id) if run_id else None
-        self._tracer = get_tracer(run_id)
+        self._tracer = CheckTracer(run_id)
         self._mesh = mesh if mesh is not None else default_mesh()
         n = self._mesh.devices.size
         self._n = n
@@ -248,6 +281,9 @@ class ShardedTpuBfsChecker(Checker):
         self._sieve_bits = sieve_bloom_bits
         self._sieve_dev = None
         self._last_comms = None
+        # Key lanes every all-to-all of this run carried, summed over
+        # devices: each drain and wave span records what it added.
+        self._exchange_lanes = 0
         self._last_comms_per = None
         # Fleet skew forensics (telemetry/fleet.py): per-shard per-wave
         # rows ride the existing out_specs=P("fp") pulls (five extra
@@ -432,76 +468,66 @@ class ShardedTpuBfsChecker(Checker):
             wave_donate = (0, 8, 9)
             deep_in = deep_in + (P("fp"), P("fp"))
             deep_donate = (0, 1, 7, 8)
-        self._jit_wave = jax.jit(
-            jax.shard_map(
-                self._wave_local,
-                mesh=self._mesh,
-                in_specs=wave_in,
-                out_specs=P("fp"),
-                check_vma=False,
-            ),
-            donate_argnums=wave_donate,
+
+        def sharded(fn, in_specs, **jit_kwargs):
+            return jax.jit(
+                jax.shard_map(
+                    fn,
+                    mesh=self._mesh,
+                    in_specs=in_specs,
+                    out_specs=P("fp"),
+                    check_vma=False,
+                ),
+                **jit_kwargs,
+            )
+
+        def shared(kind, fn, in_specs, **jit_kwargs):
+            return _ShardedJit(
+                self, kind, sharded(fn, in_specs, **jit_kwargs)
+            )
+
+        # Every program runs as an AOT executable obtained by _obtain:
+        # this check's, the shared AOT cache's, the disk store's, or a
+        # compile. The wave and the deep drain are dispatched directly,
+        # the smaller programs through _ShardedJit.
+        self._jit_wave = sharded(
+            self._wave_local, wave_in, donate_argnums=wave_donate
         )
-        self._wave_exec = {}  # (local capacity, chunk width) -> AOT wave
-        # Disk tier of the wave-executable cache (warm-start plane,
-        # storage/persist.py): bound lazily at the first wave dispatch —
-        # the trace-relevant attributes (liveness, coverage, sieve) are
-        # not all set yet at this point in __init__. The deep drain is
-        # NOT disk-cached here: its compile site pre-compiles inline and
-        # dispatches through the jit object, so there is no executable
-        # handle to persist without restructuring the drain loop.
+        self._jit_deep_drain = sharded(
+            self._deep_drain_local, deep_in, donate_argnums=deep_donate
+        )
+        # Disk tier of the executable cache (warm-start plane,
+        # storage/persist.py): bound lazily at the first dispatch, once
+        # every trace-relevant attribute is set.
         self._aot_store_arg = aot_store
         self._aot_disk = None
-        self._jit_insert = jax.jit(
-            jax.shard_map(
-                self._insert_local,
-                mesh=self._mesh,
-                in_specs=(P("fp"),) * 4,
-                out_specs=P("fp"),
-                check_vma=False,
-            ),
-            donate_argnums=(0,),
+        self._jit_insert = shared(
+            "insert", self._insert_local, (P("fp"),) * 4, donate_argnums=(0,)
         )
         # Only the destination table (arg 1) can alias the output; the
         # old, smaller table is freed by the caller's rebind.
-        self._jit_rehash = jax.jit(
-            jax.shard_map(
-                self._rehash_local,
-                mesh=self._mesh,
-                in_specs=(P("fp"), P("fp")),
-                out_specs=P("fp"),
-                check_vma=False,
-            ),
+        self._jit_rehash = shared(
+            "rehash", self._rehash_local, (P("fp"), P("fp")),
             donate_argnums=(1,),
         )
-        self._jit_deep_drain = jax.jit(
-            jax.shard_map(
-                self._deep_drain_local,
-                mesh=self._mesh,
-                in_specs=deep_in,
-                out_specs=P("fp"),
-                check_vma=False,
-            ),
-            donate_argnums=deep_donate,
+        self._jit_ring_push = shared(
+            "ring_push", self._push_local, (P("fp"),) * 4, donate_argnums=(0,)
         )
-        self._jit_ring_push = jax.jit(
-            jax.shard_map(
-                self._push_local,
-                mesh=self._mesh,
-                in_specs=(P("fp"),) * 4,
-                out_specs=P("fp"),
-                check_vma=False,
-            ),
-            donate_argnums=(0,),
+        self._jit_ring_export = shared(
+            "ring_export", self._ring_export_local, (P("fp"),) * 3
         )
-        self._jit_ring_export = jax.jit(
-            jax.shard_map(
-                self._ring_export_local,
-                mesh=self._mesh,
-                in_specs=(P("fp"),) * 3,
-                out_specs=P("fp"),
-                check_vma=False,
-            )
+        # Pre-sharded allocations (shape and dtype static).
+        self._jit_zeros = _ShardedJit(
+            self, "zeros",
+            jax.jit(jnp.zeros, static_argnums=(0, 1),
+                    out_shardings=self._shard),
+            static=2,
+        )
+        self._jit_pool = _ShardedJit(
+            self, "pool",
+            jax.jit(lambda rows: ring_rows(model, rows), static_argnums=0,
+                    out_shardings=self._shard),
+            static=1,
         )
         # Fingerprints go through the model's view hook (e.g. actor systems
         # exclude crash flags, mirroring the host state hash).
@@ -540,13 +566,20 @@ class ShardedTpuBfsChecker(Checker):
                 instruments=self._live_ins, spill_dir=spill_dir,
                 host_budget_mib=host_budget_mib,
             )
-        self._jit_fp_batch = jax.jit(jax.vmap(self._fp_fn))
+
+        def shared_jit(kind, fn):
+            return _ShardedJit(self, kind, jax.jit(fn))
+
+        self._jit_fp_batch = shared_jit("fp_batch", jax.vmap(self._fp_fn))
         self._jit_key_batch = (
-            jax.jit(self._key_fn)
+            shared_jit("key_batch", self._key_fn)
             if self._symmetry_enabled
             else self._jit_fp_batch
         )
-        self._jit_fp_single = jax.jit(self._fp_fn)
+        self._jit_fp_single = shared_jit("fp_single", self._fp_fn)
+        self._jit_within = shared_jit(
+            "within", jax.vmap(model.packed_within_boundary)
+        )
 
         # Telemetry: one span per host-visible wave/drain (see
         # stateright_tpu.telemetry); occupancy is global across shards.
@@ -579,6 +612,26 @@ class ShardedTpuBfsChecker(Checker):
             symmetry=self._symmetry_enabled,
         )
         self.donation_enabled = True
+        # Compiled programs keyed (kind, shapes): waves by (table rows,
+        # chunk width), deep drains by (table rows, ring rows), the rest
+        # by their arguments. With ``aot_cache=<namespace>`` the dict is
+        # the process-global one (checker/tpu.py ``shared_aot_cache``)
+        # for this checker's trace signature, so checks of one
+        # configuration share every program, the drains compiled for
+        # grown tables included. ``_exe_held``: the keys this check has
+        # obtained (each first use is one ``sharded_bfs.exe`` span).
+        self._exec = {}
+        self._exe_held = set()
+        if aot_cache is not None:
+            if self._sym_scheme == CUSTOM_REP_SCHEME:
+                raise ValueError(
+                    "aot_cache cannot be shared under a custom "
+                    "symmetry_fn: the traced key function is caller "
+                    "code the cache signature cannot compare"
+                )
+            self._exec = shared_aot_cache(
+                aot_cache, ("sharded",) + self._aot_signature()
+            )
 
         self._handles = [
             threading.Thread(target=self._run, name="sharded-tpu-bfs", daemon=True)
@@ -1387,41 +1440,37 @@ class ShardedTpuBfsChecker(Checker):
 
     def _run(self):
         try:
-            self._explore()
-        except BaseException as e:  # noqa: BLE001 - surfaced via worker_error
-            self._error = e
-            self._abort_attribution()
+            # The check's root span: every span of this check descends
+            # from it, on this thread and on its caller's (discoveries()).
+            with self._tracer.span("sharded_bfs.check", devices=self._n) as check:
+                self._tracer.root = check.id
+                try:
+                    self._explore()
+                except BaseException as e:  # noqa: BLE001 - worker_error
+                    self._error = e
+                    self._abort_attribution()
+                finally:
+                    self._shutdown_pipeline()
+                    self._finalize_coverage(set(self._discoveries_fp))
+                    check.set(unique=self._unique_count)
         finally:
-            self._shutdown_pipeline()
-            self._finalize_coverage(set(self._discoveries_fp))
             self._done_event.set()
 
     def _new_table(self):
         # Allocate pre-sharded: materializing the global table on one device
         # first would OOM exactly when shards are sized near per-device HBM.
         # Each shard carries the probe apron the hashset ops expect.
-        return jax.jit(
-            lambda: jnp.zeros(
-                (self._n, self._cap_loc + MAX_PROBES, 2), jnp.uint32
-            ),
-            out_shardings=self._shard,
-        )()
+        return self._jit_zeros(
+            (self._n, self._cap_loc + MAX_PROBES, 2), jnp.uint32
+        )
 
     def _new_sieve(self):
         """Cold (flushed) sieve state, pre-sharded: one receipt cache and
         one Bloom filter per device. Cold is always safe — kills only
         become possible again as keys are re-routed and re-acked."""
         return (
-            jax.jit(
-                lambda: jnp.zeros(
-                    (self._n, self._sieve_slots, 2), jnp.uint32
-                ),
-                out_shardings=self._shard,
-            )(),
-            jax.jit(
-                lambda: jnp.zeros((self._n, self._sieve_bits), jnp.uint8),
-                out_shardings=self._shard,
-            )(),
+            self._jit_zeros((self._n, self._sieve_slots, 2), jnp.uint32),
+            self._jit_zeros((self._n, self._sieve_bits), jnp.uint8),
         )
 
     def _grow_table(self, table, min_cap_loc, defer_evict=False):
@@ -1929,6 +1978,7 @@ class ShardedTpuBfsChecker(Checker):
         wave_generated = 0
         wave_new = 0
         self._wave_stale = 0
+        lanes0 = self._exchange_lanes
         with self._tracer.span("sharded_bfs.wave", wave=chunks) as sp:
             while True:
                 wave = self._call_wave(table, dev, depth_cap)
@@ -1971,6 +2021,7 @@ class ShardedTpuBfsChecker(Checker):
                 bucket=bucket,
                 compaction_ratio=(got / width if bucket else None),
                 live_lanes=got,
+                exchange_lanes=self._exchange_lanes - lanes0,
             )
             if self._cov is not None:
                 self._cov.emit_wave_span()
@@ -1986,9 +2037,12 @@ class ShardedTpuBfsChecker(Checker):
         synchronous order; see TpuBfsChecker._consume_wave_async)."""
         attempt = 0
         ctx = {"wave_new": 0, "stale": 0, "generated": 0}
+        lanes0 = self._exchange_lanes
         while True:
             wave = self._call_wave(table, dev, depth_cap)
             table = wave["table"]
+            # Every attempt's exchange, read by the final verdict.
+            ctx["exchange_lanes"] = self._exchange_lanes - lanes0
             if attempt == 0:
                 ctx["generated"] = self._apply_wave_stats(wave)
             if self._cov is not None:
@@ -2088,6 +2142,7 @@ class ShardedTpuBfsChecker(Checker):
                     compaction_ratio=(got / width if bucket else None),
                     live_lanes=got, stale=ctx["stale"], warm=warm,
                     state=state, comms=comms,
+                    exchange_lanes=ctx["exchange_lanes"],
                 )
         finally:
             # Decrement even on a verdict error: the barrier predicate
@@ -2117,17 +2172,40 @@ class ShardedTpuBfsChecker(Checker):
         path = self._checkpoint_path
         self._pipe.submit(lambda: self._checkpoint_write(path, payload))
 
+    def _aot_signature(self) -> tuple:
+        """Everything the wave and drain traces close over, for the shared
+        and disk executable caches: backend, the mesh's devices, model
+        digest, properties, key scheme, widths, ladder, log and drain caps,
+        sieve, liveness, coverage, fleet. Shapes that change within a run
+        (table and ring rows, chunk width) ride the per-entry keys."""
+        return (
+            jax.default_backend(),
+            jax.process_count(),
+            tuple(int(d.id) for d in self._mesh.devices.flat),
+            packed_model_digest(self._model, self._A),
+            tuple(
+                (p.name, str(p.expectation)) for p in self._properties
+            ),
+            self._sym_scheme,
+            self._F_loc,
+            tuple(self._buckets),
+            self._Ll,
+            self._max_drain_waves,
+            bool(self._sieve),
+            self._sieve_slots if self._sieve else None,
+            self._sieve_bits if self._sieve else None,
+            self._live_enabled,
+            self._cov is not None,
+            self._fleet_on,
+        )
+
     def _aot_disk_binding(self):
-        """The disk AOT binding, built on first use (every trace-relevant
-        attribute is set by the first wave). The signature mirrors the
-        single-device checker's ``_aot_signature`` for the knobs the
-        sharded trace closes over — backend, topology, model digest,
-        properties, capacities, ladder, sieve, liveness, coverage — so a
-        config drift misses instead of loading the wrong executable."""
+        """The disk AOT binding, built on first use, under
+        ``_aot_signature`` so a configuration drift misses instead of
+        loading the wrong executable."""
         if self._aot_store_arg is None:
             return None
         if self._aot_disk is None:
-            from ..checker.tpu import packed_model_digest
             from ..storage.persist import AotDiskStore
 
             store = (
@@ -2135,30 +2213,61 @@ class ShardedTpuBfsChecker(Checker):
                 if isinstance(self._aot_store_arg, AotDiskStore)
                 else AotDiskStore(self._aot_store_arg)
             )
-            sig = (
-                "sharded_wave",
-                jax.default_backend(),
-                jax.process_count(),
-                self._n,
-                packed_model_digest(self._model, self._A),
-                tuple(
-                    (p.name, str(p.expectation)) for p in self._properties
-                ),
-                self._F_loc,
-                self._cap_loc,
-                tuple(self._buckets),
-                bool(self._sieve),
-                self._sieve_slots if self._sieve else None,
-                self._sieve_bits if self._sieve else None,
-                self._live_enabled,
-                self._cov is not None,
-                self._fleet_on,
-            )
             self._aot_disk = store.binding(
-                f"sharded:{type(self._model).__name__}", sig,
-                registry=self._registry,
+                f"sharded:{type(self._model).__name__}",
+                self._aot_signature(), registry=self._registry,
             )
         return self._aot_disk
+
+    def _obtain(self, kind, key, jit_fn, args):
+        """The compiled ``jit_fn`` for ``args`` under ``(kind,) + key``:
+        the one this check holds, else the shared AOT cache's, else (wave
+        and drain) the disk store's, else a compile. A compile while the
+        run explores is added to ``warmup_seconds``. Each first use in a
+        check is one ``sharded_bfs.exe`` span, ``source`` ``shared``,
+        ``disk``, ``compile`` or ``persistent_cache``."""
+        full = (kind,) + key
+        exe = self._exec.get(full)
+        if exe is not None and full in self._exe_held:
+            return exe
+        step = kind in ("wave", "drain")
+        disk = self._aot_disk_binding() if step else None
+        with exe_span(self._tracer, "sharded_bfs.exe", kind) as sp:
+            if step:
+                sp.set(table_capacity=key[0], width=key[1])
+            if exe is not None:
+                sp.set(source="shared")
+                if disk is not None:
+                    disk.ensure(kind, key, exe)
+            elif disk is not None:
+                t0 = time.perf_counter()
+                exe = disk.load(kind, key)
+                if exe is not None:
+                    self._exec[full] = exe
+                    sp.set(
+                        source="disk",
+                        build_ms=(time.perf_counter() - t0) * 1e3,
+                    )
+            if exe is None:
+                t0 = time.perf_counter()
+                # The attribution engine's compile-detection site for
+                # waves and drains: only a miss of every cache enters.
+                with (
+                    self._phase("compile") if step
+                    else contextlib.nullcontext()
+                ):
+                    exe = jit_fn.lower(*args).compile()
+                self._exec[full] = exe
+                if (
+                    getattr(self, "warmup_seconds", None) is not None
+                    and not self._done_event.is_set()
+                ):
+                    self.warmup_seconds += time.perf_counter() - t0
+                    self._wi.warmup.set(self.warmup_seconds)
+                if disk is not None:
+                    disk.save(kind, key, exe)
+        self._exe_held.add(full)
+        return exe
 
     def _call_wave(self, table, dev, depth_cap):
         """Wave through an AOT-compiled executable (keyed by local table
@@ -2182,35 +2291,9 @@ class ShardedTpuBfsChecker(Checker):
         )
         if self._sieve:
             args = args + self._sieve_dev
-        key = (table.shape[0], dev["hi"].shape[0])
-        exe = self._wave_exec.get(key)
-        if exe is not None:
-            disk = self._aot_disk_binding()
-            if disk is not None:
-                # Warm-memory / cold-disk backfill, same as the solo
-                # checker's wave site.
-                disk.ensure("wave", key, exe)
-        if exe is None:
-            disk = self._aot_disk_binding()
-            if disk is not None:
-                # Disk tier (warm-start plane): a fenced hit skips the
-                # compile phase entirely — cross-process sharded runs
-                # record zero wave compiles.
-                exe = disk.load("wave", key)
-                if exe is not None:
-                    self._wave_exec[key] = exe
-        if exe is None:
-            t0 = time.perf_counter()
-            # AOT-cache miss: the attribution engine's compile-detection
-            # site (the hit path never enters this branch).
-            with self._phase("compile"):
-                exe = self._jit_wave.lower(*args).compile()
-            self._wave_exec[key] = exe
-            if self.warmup_seconds is not None:
-                self.warmup_seconds += time.perf_counter() - t0
-            disk = self._aot_disk_binding()
-            if disk is not None:
-                disk.save("wave", key, exe)
+        exe = self._obtain(
+            "wave", (table.shape[1], dev["hi"].shape[0]), self._jit_wave, args
+        )
         if self._attr is None:
             out = exe(*args)
         else:
@@ -2241,6 +2324,7 @@ class ShardedTpuBfsChecker(Checker):
         per = np.asarray(self._pull(comms), np.int64)  # (n, vec)
         self._last_comms_per = per
         c = per.sum(axis=0)
+        self._exchange_lanes += int(c[5])
         args = self._ci.record(
             probes=int(c[0]),
             killed=int(c[1]),
@@ -2293,16 +2377,10 @@ class ShardedTpuBfsChecker(Checker):
     # -- deep-drain host loop ---------------------------------------------
 
     def _new_pool(self):
-        W = self._n * self._PCl
-        return jax.jit(
-            lambda: ring_rows(self._model, W), out_shardings=self._shard
-        )()
+        return self._jit_pool(self._n * self._PCl)
 
     def _new_heads(self):
-        return jax.jit(
-            lambda: jnp.zeros((self._n,), jnp.int32),
-            out_shardings=self._shard,
-        )()
+        return self._jit_zeros((self._n,), jnp.int32)
 
     def _feed_rings(self, pool, head, count, ring_est):
         """Moves host-pool rows into the device rings, growing them when
@@ -2343,7 +2421,6 @@ class ShardedTpuBfsChecker(Checker):
         count = self._new_heads()
         ring_est = 0  # conservative host bound on the fullest ring
         drains = 0
-        compiled = False
         last_checkpoint = time.perf_counter()
         while True:
             if len(self._discoveries_fp) == len(props):
@@ -2410,21 +2487,19 @@ class ShardedTpuBfsChecker(Checker):
                 )
                 if self._sieve:
                     args = args + self._sieve_dev
-                if not compiled:
-                    # AOT-compile so the first drain (which may run the whole
-                    # exploration) doesn't fold into any warmup measurement.
-                    with self._phase("compile"):
-                        self._jit_deep_drain.lower(*args).compile()
-                    compiled = True
-                    if self.warmup_seconds is None:
-                        self.warmup_seconds = (
-                            time.perf_counter() - self._t_start
-                        )
-                        self._wi.warmup.set(self.warmup_seconds)
+                # Obtained before the first drain runs (which may run the
+                # whole exploration), so no compile folds into it.
+                exe = self._obtain(
+                    "drain", (table.shape[1], self._PCl),
+                    self._jit_deep_drain, args,
+                )
+                if self.warmup_seconds is None:
+                    self.warmup_seconds = time.perf_counter() - self._t_start
+                    self._wi.warmup.set(self.warmup_seconds)
                 drain_span = self._tracer.span("sharded_bfs.drain", drain=drains)
                 with drain_span:
                     with self._phase("device"):
-                        res = self._jit_deep_drain(*args)
+                        res = exe(*args)
                         if self._attr is not None:
                             self._attr.fence(res)
                     dstats = self._pull(res["drain_stats"])  # (n, 10)
@@ -2503,11 +2578,15 @@ class ShardedTpuBfsChecker(Checker):
                                 self._key_log.append(
                                     fp64_pairs(pack[d, 4, :ln], pack[d, 5, :ln])
                                 )
+                lanes0 = self._exchange_lanes
                 with self._tracer.span("sharded_bfs.wave", drain=drains) as sp:
                     table, pool, head, count, ring_est = self._consume_final(
                         res, dstats, table, pool, head, count, ring_est,
                         depth_cap, span=sp,
                     )
+                    # The drain span counted this wave's exchange; only an
+                    # overflow retry's is this span's.
+                    sp.set(**self._ci.exchange(self._exchange_lanes - lanes0))
         self._record_placement(table)
         self._audit_table(table)
 
@@ -2677,9 +2756,7 @@ class ShardedTpuBfsChecker(Checker):
         else:
             khi, klo = hi, lo
         in_range = np.arange(width) < n0
-        bound = np.asarray(
-            jax.jit(jax.vmap(model.packed_within_boundary))(init_np)
-        )
+        bound = np.asarray(self._jit_within(init_np))
         valid = in_range & bound
 
         table = self._new_table()
@@ -3029,7 +3106,7 @@ class ShardedTpuBfsChecker(Checker):
     def _record_wave_metrics(
         self, span, frontier, generated, n_new, bucket=None,
         compaction_ratio=None, live_lanes=None, stale=None, warm=None,
-        state=None, comms=None,
+        state=None, comms=None, exchange_lanes=None,
     ):
         """One host-visible wave's telemetry (the shared bundle does the
         recording; occupancy is the shard tables' resident load — under
@@ -3052,6 +3129,9 @@ class ShardedTpuBfsChecker(Checker):
         cm = comms if comms is not None else self._last_comms
         if cm:
             extra.update(cm)
+        if exchange_lanes is not None:
+            # Every attempt's exchange, growth retries included.
+            extra.update(self._ci.exchange(exchange_lanes))
         if state is not None:
             l0, capacity, depth = state
         else:
@@ -3140,16 +3220,17 @@ class ShardedTpuBfsChecker(Checker):
     supports_device_liveness = True
 
     def discoveries(self) -> Dict[str, Path]:
-        out = {
-            name: self._reconstruct(fp)
-            for name, fp in list(self._discoveries_fp.items())
-        }
-        out = self._with_device_liveness(out)
-        return self._with_lassos(
-            out,
-            self._done_event.is_set(),
-            set(self._discoveries_fp) | set(self._live_paths),
-        )
+        with self._tracer.span("sharded_bfs.paths"):
+            out = {
+                name: self._reconstruct(fp)
+                for name, fp in list(self._discoveries_fp.items())
+            }
+            out = self._with_device_liveness(out)
+            return self._with_lassos(
+                out,
+                self._done_event.is_set(),
+                set(self._discoveries_fp) | set(self._live_paths),
+            )
 
     def handles(self) -> List[threading.Thread]:
         handles, self._handles = self._handles, []

@@ -1414,14 +1414,8 @@ class CheckService:
             # device table (see _validate_budget).
             spawn["table_capacity"] = job.derived_table_capacity
         spawn["run_id"] = job.run_id
-        # Cross-job executable sharing is a single-device-checker
-        # feature for now (the sharded checker has no aot_cache knob);
-        # passing it unconditionally would TypeError every job under
-        # spawn_method="spawn_sharded_tpu_bfs".
-        if (
-            job.aot_namespace is not None
-            and self.spawn_method == "spawn_tpu_bfs"
-        ):
+        # Cross-job executable sharing (both device checkers).
+        if job.aot_namespace is not None:
             spawn.setdefault("aot_cache", job.aot_namespace)
         # Persistent AOT plane: the disk store rides along wherever a
         # checker can use it — the solo checker needs a namespace (its

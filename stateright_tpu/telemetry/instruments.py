@@ -180,8 +180,15 @@ class CommsInstruments:
             "comms_bloom_probes": bloom_probes,
             "comms_bloom_hits": bloom_hits,
             "comms_bloom_fps": bloom_fps,
-            "comms_lanes": lanes,
-            "comms_bytes": lanes * self.LANE_BYTES,
+            **self.exchange(lanes),
+        }
+
+    @classmethod
+    def exchange(cls, lanes: int) -> dict:
+        """The span args of ``lanes`` key lanes the all-to-all carried."""
+        return {
+            "exchange_lanes": lanes,
+            "exchange_bytes": lanes * cls.LANE_BYTES,
         }
 
 

@@ -110,6 +110,47 @@ def test_staged_drain_compiles_at_smoke_width(leg, one_chip, monkeypatch):
     assert compiled.memory_analysis().argument_size_in_bytes > 0
 
 
+def test_sharded_drain_compiles_for_four_chips(topo, monkeypatch):
+    """The sharded deep drain over the four chips of a v5e host, at the
+    widths ``chip_smoke.py --chips 4`` and the ``2pc7.sharded4`` cell run
+    (2pc-7, 2,048 lanes and 2^18 rows per chip): key exchange and row
+    balance by ``all_to_all``, the drain's exit by ``psum``."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from stateright_tpu.ops.ring import ring_rows
+    from stateright_tpu.parallel.sharded import ShardedTpuBfsChecker
+
+    monkeypatch.setattr(
+        ShardedTpuBfsChecker, "_run", lambda self: self._done_event.set()
+    )
+    mesh = Mesh(np.array(topo.devices), ("fp",))
+    model = TwoPhaseSys(7)
+    checker = ShardedTpuBfsChecker(
+        model.checker(), mesh=mesh,
+        frontier_per_device=chip_smoke.SHARDED_FRONTIER_PER_DEVICE,
+        table_capacity_per_device=chip_smoke.SHARDED_TABLE_PER_DEVICE,
+    )
+    shard, rep = NamedSharding(mesh, P("fp")), NamedSharding(mesh, P())
+
+    def s(shape, dtype, sharding=shard):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    pool = jax.tree_util.tree_map(
+        lambda x: s(x.shape, x.dtype),
+        jax.eval_shape(lambda: ring_rows(model, 4 * checker._PCl)),
+    )
+    i32 = s((), jnp.int32, rep)
+    args = (
+        s((4, checker._cap_loc + MAX_PROBES, 2), jnp.uint32), pool,
+        s((4,), jnp.int32), s((4,), jnp.int32),
+        s((len(checker._properties),), jnp.bool_, rep), i32, i32,
+    )
+    compiled = checker._jit_deep_drain.lower(*args).compile()
+    assert "all-to-all" in compiled.as_text()
+    assert compiled.memory_analysis().argument_size_in_bytes > 0
+
+
 @pytest.mark.parametrize("batch", [4096, 1 << 17])
 def test_pallas_insert_compiles(batch, one_chip):
     """The tile-sweep insert at a 2^20-row table: keys staged through
@@ -176,6 +217,15 @@ def test_smoke_phase_on_cpu():
             "2pc check 3", TwoPhaseSys(3),
             dict(frontier_capacity=64, table_capacity=1 << 12), 289,
         )
+
+
+def test_smoke_sharded_phase_on_cpu():
+    """The same phase spread over two devices (``devices=2``), as
+    ``chip_smoke.py --chips 4`` spreads 2pc check 7 over four."""
+    spawn = dict(devices=2, frontier_per_device=32,
+                 table_capacity_per_device=1 << 11)
+    checker = chip_smoke.run_phase("2pc check 3", TwoPhaseSys(3), spawn, 288)
+    assert len({d for d, _ in checker.visited_placement}) == 2
 
 
 def test_smoke_refuses_without_tpu(capsys):
