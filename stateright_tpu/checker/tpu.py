@@ -65,7 +65,7 @@ from ..ops.hashset import (
 )
 from ..ops.ring import ring_export, ring_push, ring_rows, ring_take
 from ..telemetry import CheckTracer, WaveInstruments, metrics_registry
-from ..telemetry.executables import TracedJit, exe_span
+from ..telemetry.executables import AotJit, exe_span
 from ..utils.faults import fault_point
 from .base import _NULL_CTX, Checker  # noqa: F401 - _NULL_CTX re-exported
 from .pipeline import HostPipeline
@@ -472,6 +472,36 @@ def min_admissible_hbm_budget_mib(model, frontier_capacity: int) -> float:
     return ((rows + MAX_PROBES) * 8) / (1 << 20)
 
 
+def compact(chunk, width):
+    """Gathers a chunk's live lanes into a dense prefix and narrows it to
+    ``width`` (the chosen bucket), so masked padding lanes never reach the
+    expand grid. The cumsum scatter is stable — live lanes keep their
+    relative order, so in-wave dedup tie-breaks (first claim wins by lane
+    order) pick the same winner as the fixed-width dispatch and the
+    bucketed path stays bit-identical."""
+    mask = chunk["mask"]
+    pos = jnp.cumsum(mask.astype(jnp.int32)) - 1
+    # Scatter straight into the bucket-wide buffer (the chosen bucket
+    # holds every live lane by construction; the < width guard only drops
+    # lanes a torn mask could produce) — a full-width scatter sliced
+    # afterwards would still write O(F_max) bytes per leaf.
+    dest = jnp.where(mask & (pos < width), pos, width)
+
+    def scat(x):
+        z = jnp.zeros((width,) + x.shape[1:], x.dtype)
+        return z.at[dest].set(x, mode="drop")
+
+    out = {
+        k: jax.tree_util.tree_map(scat, v) if k == "states" else scat(v)
+        for k, v in chunk.items()
+        if k != "mask"
+    }
+    out["mask"] = jnp.arange(width, dtype=jnp.int32) < mask.sum(
+        dtype=jnp.int32
+    )
+    return out
+
+
 # -- cross-checker AOT executable sharing (checking-as-a-service) -----------
 #
 # One resident process serving many jobs must never recompile a wave shape
@@ -578,8 +608,8 @@ class TpuBfsChecker(Checker):
         self.run_id = run_id
         self._registry = metrics_registry(run_id) if run_id else None
         self._tracer = CheckTracer(run_id)
-        # Executables this check already holds, by ("wave"|"drain", key):
-        # a later lookup of one is a reuse, not an acquisition.
+        # Executables this check already holds, by (kind,) + key: a later
+        # lookup of one is a reuse, not an acquisition.
         self._exe_held = set()
         self._drains = 0
         self._fp_calls = 0
@@ -1045,10 +1075,14 @@ class TpuBfsChecker(Checker):
         # pays for exactly one drain compile.
         self._drain_jits = {}
         self._drain_exec = {}
-        # Cross-job sharing: with ``aot_cache="<namespace>"`` the two
+        # Every smaller program (pool, seed, frontier slicing, table
+        # growth, fingerprints) runs as an AOT executable from _obtain,
+        # keyed (kind, static args, argument avals).
+        self._prog_exec = {}
+        # Cross-job sharing: with ``aot_cache="<namespace>"`` the three
         # executable dicts come from the process-global cache instead, so
-        # same-shaped waves across checker instances (the service's jobs,
-        # a preempted job's resumed incarnation) never recompile. The
+        # same-shaped programs across checker instances (the service's
+        # jobs, a preempted job's resumed incarnation) never retrace. The
         # namespace asserts semantic equivalence the trace signature
         # cannot see (e.g. property conditions closing over model fields
         # outside the packed arrays); the signature guards everything it
@@ -1064,6 +1098,7 @@ class TpuBfsChecker(Checker):
             sig = self._aot_signature()
             self._wave_exec = shared_aot_cache(aot_cache, ("wave",) + sig)
             self._drain_exec = shared_aot_cache(aot_cache, ("drain",) + sig)
+            self._prog_exec = shared_aot_cache(aot_cache, ("prog",) + sig)
         # Disk tier of the AOT cache (warm-start plane): serialized
         # executables persist under the service dir, fenced on
         # jax-version/backend/topology so a fresh PROCESS serves its
@@ -1088,34 +1123,29 @@ class TpuBfsChecker(Checker):
             self._aot_disk = store.binding(
                 aot_cache, self._aot_signature(), registry=self._registry
             )
-        def traced(kind, fn, **jit_kwargs):
-            return TracedJit(
-                jax.jit(fn, **jit_kwargs), self._tracer, "tpu_bfs.exe", kind
-            )
+        def aot(kind, fn, **jit_kwargs):
+            return AotJit(self._obtain, kind, fn, **jit_kwargs)
 
-        self._jit_pool_zero = traced(
+        self._jit_pool_zero = aot(
             "pool_zero", self._pool_zero, static_argnums=(0,)
         )
         # The ring is rebound to the returned one; the pushed chunk's
         # buffers cannot alias the ring (scatter), so donating them would
         # only trade a copy for an unusable-donation warning.
-        self._jit_pool_push = traced(
+        self._jit_pool_push = aot(
             "pool_push", self._pool_push, donate_argnums=(0,)
         )
-        self._jit_pool_export = traced("pool_export", self._pool_export)
-        self._jit_init = traced("init", self._init_wave, donate_argnums=(0,))
-        self._jit_take = traced("take", self._take, static_argnums=(2,))
-        self._jit_finish = traced("finish", self._finish, static_argnums=(2,))
-        self._jit_materialize = traced("materialize", self._materialize)
+        self._jit_pool_export = aot("pool_export", self._pool_export)
+        self._jit_init = aot("init", self._init_wave, donate_argnums=(0,))
+        self._jit_take = aot("take", self._take, static_argnums=(2,))
+        self._jit_finish = aot("finish", self._finish, static_argnums=(2,))
+        self._jit_materialize = aot("materialize", self._materialize)
         # Only the destination table (arg 1) can alias the output; the old
         # table has a different shape and is freed by the caller's rebind.
-        self._jit_rehash = traced(
-            "rehash", self._rehash, donate_argnums=(1,)
-        )
-        self._jit_fp_single = traced("fp_single", self._fp_fn)
-        # (in_width, bucket) -> jitted live-lane compaction (see
-        # _compact_chunk).
-        self._compact_exec = {}
+        self._jit_rehash = aot("rehash", self._rehash, donate_argnums=(1,))
+        self._jit_fp_single = aot("fp_single", self._fp_fn)
+        # Live-lane compaction to a bucket width (see _compact_chunk).
+        self._jit_compact = aot("compact", compact, static_argnums=(1,))
         self.donation_enabled = True
         self._last_dispatch = None  # (bucket, live) of the last chunk wave
 
@@ -2049,49 +2079,8 @@ class TpuBfsChecker(Checker):
 
     def _compact_chunk(self, chunk, width):
         """Gathers a chunk's live lanes into a dense prefix and narrows it
-        to ``width`` (the chosen bucket), so masked padding lanes never
-        reach the expand grid. The cumsum scatter is stable — live lanes
-        keep their relative order, so in-wave dedup tie-breaks (first
-        claim wins by lane order) pick the same winner as the fixed-width
-        dispatch and the bucketed path stays bit-identical."""
-        key = (chunk["hi"].shape[0], width)
-        fn = self._compact_exec.get(key)
-        if fn is None:
-
-            def compact(c):
-                mask = c["mask"]
-                pos = jnp.cumsum(mask.astype(jnp.int32)) - 1
-                # Scatter straight into the bucket-wide buffer (the
-                # chosen bucket holds every live lane by construction;
-                # the < width guard only drops lanes a torn mask could
-                # produce) — a full-width scatter sliced afterwards
-                # would still write O(F_max) bytes per leaf.
-                dest = jnp.where(mask & (pos < width), pos, width)
-
-                def scat(x):
-                    z = jnp.zeros((width,) + x.shape[1:], x.dtype)
-                    return z.at[dest].set(x, mode="drop")
-
-                out = {
-                    k: (
-                        jax.tree_util.tree_map(scat, v)
-                        if k == "states"
-                        else scat(v)
-                    )
-                    for k, v in c.items()
-                    if k != "mask"
-                }
-                out["mask"] = (
-                    jnp.arange(width, dtype=jnp.int32)
-                    < mask.sum(dtype=jnp.int32)
-                )
-                return out
-
-            fn = TracedJit(
-                jax.jit(compact), self._tracer, "tpu_bfs.exe", "compact"
-            )
-            self._compact_exec[key] = fn
-        return fn(chunk)
+        to ``width`` (the chosen bucket); see ``compact``."""
+        return self._jit_compact(chunk, width)
 
     def _call_wave(self, table, chunk, depth_cap):
         """Runs one wave through an AOT-compiled executable keyed on
@@ -2151,7 +2140,7 @@ class TpuBfsChecker(Checker):
             args = (table, self._elog) + args[1:]
         key = (table.shape[0], chunk["hi"].shape[0])
         exe = self._wave_exec.get(key)
-        if exe is None or ("wave", key) not in self._exe_held:
+        if exe is None or ("wave",) + key not in self._exe_held:
             exe = self._obtain_wave(key, exe, args)
         elif self._aot_disk is not None:
             # Warm-memory / cold-disk: backfill so a later fresh process
@@ -2946,6 +2935,24 @@ class TpuBfsChecker(Checker):
         )
         return newq
 
+    def _obtain(self, kind, key, jit_fn, args):
+        """The compiled ``jit_fn`` for ``args`` under ``(kind,) + key``: the
+        one this check holds, else the shared AOT cache's (or, without
+        ``aot_cache``, this checker's own), else one lowered and compiled
+        now, through the persistent compilation cache. Each first use in a
+        check is one ``tpu_bfs.exe`` span, ``source`` ``memory`` on a cache
+        hit; a compile here is not added to ``warmup_seconds``."""
+        full = (kind,) + key
+        exe = self._prog_exec.get(full)
+        if exe is not None and full in self._exe_held:
+            return exe
+        with exe_span(self._tracer, "tpu_bfs.exe", kind):
+            if exe is None:
+                exe = jit_fn.lower(*args).compile()
+                self._prog_exec[full] = exe
+        self._exe_held.add(full)
+        return exe
+
     def _obtain_wave(self, key, exe, args):
         """A wave executable this check does not hold yet, keyed on (table
         rows, bucket width): ``exe`` from the shared AOT cache, else the
@@ -2993,7 +3000,7 @@ class TpuBfsChecker(Checker):
                     self._wi.warmup.set(self.warmup_seconds)
                 if self._aot_disk is not None:
                     self._aot_disk.save("wave", key, exe)
-        self._exe_held.add(("wave", key))
+        self._exe_held.add(("wave",) + key)
         return exe
 
     def _drain_exe(self, width, args, t_start):
@@ -3004,7 +3011,7 @@ class TpuBfsChecker(Checker):
         steady-state window honest."""
         key = (width, args[0].shape[0], self._pool_capacity)
         exe = self._drain_exec.get(key)
-        if exe is not None and ("drain", key) in self._exe_held:
+        if exe is not None and ("drain",) + key in self._exe_held:
             if self._aot_disk is not None:
                 # Warm-memory / cold-disk backfill, same as the wave site.
                 self._aot_disk.ensure("drain", key, exe)
@@ -3073,7 +3080,7 @@ class TpuBfsChecker(Checker):
                     self._wi.warmup.set(self.warmup_seconds)
                 if self._aot_disk is not None:
                     self._aot_disk.save("drain", key, exe)
-        self._exe_held.add(("drain", key))
+        self._exe_held.add(("drain",) + key)
         return exe
 
     def _export_pool_chunks(self, pool, head, count):

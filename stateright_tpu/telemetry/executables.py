@@ -1,14 +1,13 @@
 """Executable acquisition as spans.
 
-A check obtains an executable when one of its jits meets a new argument
-signature (JAX traces and lowers the function, then compiles it or loads
-it from the persistent compilation cache), and when it takes a compiled
-program from an ahead-of-time cache or compiles one itself. Each
-acquisition is one span (the device checker's ``tpu_bfs.exe``) with
-``kind`` (the function), ``source`` (``memory``, ``disk``,
-``persistent_cache`` or ``compile``), ``trace_ms`` (trace and lowering)
-and ``build_ms`` (compile or load); a call that reuses an executable
-records none.
+A check obtains an executable when it meets a program at a new argument
+signature: it takes a compiled program from an ahead-of-time cache, or
+traces and lowers the function and then compiles it or loads it from the
+persistent compilation cache. Each acquisition is one span (the device
+checker's ``tpu_bfs.exe``) with ``kind`` (the program), ``source``
+(``memory``, ``disk``, ``persistent_cache`` or ``compile``), ``trace_ms``
+(trace and lowering) and ``build_ms`` (compile or load); a call that
+reuses an executable records none.
 
 JAX reports trace, lowering and compile durations, and persistent-cache
 hits, through ``jax.monitoring`` on the calling thread; the build meter
@@ -21,7 +20,8 @@ from __future__ import annotations
 import contextlib
 import threading
 
-from .trace import _now_us
+import jax
+from jax.api_util import shaped_abstractify
 
 _TRACE_EVENTS = (
     "/jax/core/compile/jaxpr_trace_duration",
@@ -52,8 +52,6 @@ def install_build_meter() -> None:
     global _meter_installed
     with _meter_lock:
         if not _meter_installed:
-            import jax
-
             jax.monitoring.register_event_duration_secs_listener(
                 _meter_duration
             )
@@ -101,32 +99,36 @@ def exe_span(tracer, name, kind, **args):
         sp.set(**{k: v for k, v in found.items() if k not in sp.args})
 
 
-class TracedJit:
-    """A ``jax.jit`` whose calls that grow its executable cache are
-    recorded as ``name`` spans of ``kind``. Whether a call obtained an
-    executable is known only once it returns (the jit's cache grew), so
-    the span is backdated to the call's start. With the tracer disabled a
-    call goes straight to the jit."""
+def aval_key(x) -> tuple:
+    """Shape, dtype and weak type of one argument leaf: what a compiled
+    executable is specialised to."""
+    a = shaped_abstractify(x)
+    return (a.shape, a.dtype, a.weak_type)
 
-    __slots__ = ("_fn", "_tracer", "_name", "_kind")
 
-    def __init__(self, fn, tracer, name, kind):
-        install_build_meter()
-        self._fn = fn
-        self._tracer = tracer
-        self._name = name
+class AotJit:
+    """A ``jax.jit`` run as ahead-of-time executables that ``obtain(kind,
+    key, jit_fn, args)`` supplies. The key holds the arguments at the
+    ``static_argnums`` positions (baked into the executable, so the call
+    drops them), the pytree structure of the others and each of their
+    leaves' ``aval_key``: callers whose ``obtain`` looks in one dict share
+    executables."""
+
+    __slots__ = ("_obtain", "_kind", "_jit", "_static")
+
+    def __init__(self, obtain, kind, fn, static_argnums=(), **jit_kwargs):
+        self._obtain = obtain
         self._kind = kind
+        self._jit = jax.jit(fn, static_argnums=static_argnums, **jit_kwargs)
+        self._static = frozenset(static_argnums)
 
     def __call__(self, *args):
-        fn = self._fn
-        if not self._tracer.enabled:
-            return fn(*args)
-        held = fn._cache_size()
-        t0 = _now_us()
-        before = build_meter()
-        out = fn(*args)
-        if fn._cache_size() > held:
-            found = acquired(before, build_meter())
-            with self._tracer.span(self._name, kind=self._kind, **found) as sp:
-                sp.since(t0)
-        return out
+        static = self._static
+        if static:
+            fixed = tuple(a for i, a in enumerate(args) if i in static)
+            dyn = tuple(a for i, a in enumerate(args) if i not in static)
+        else:
+            fixed, dyn = (), args
+        leaves, tree = jax.tree_util.tree_flatten(dyn)
+        key = (fixed, tree, tuple(map(aval_key, leaves)))
+        return self._obtain(self._kind, key, self._jit, args)(*dyn)

@@ -20,8 +20,7 @@ first span, the root a :class:`CheckTracer` view gives it (a checker's
 checker's worker thread and its caller's. Every span is also bridged
 into a ``jax.profiler.TraceAnnotation`` of the same name, so a profiler
 capture holds the program's spans on the device trace's clock; the
-bridge needs jax already imported, a disabled tracer opens none, and a
-span backdated with ``since`` keeps none.
+bridge needs jax already imported, and a disabled tracer opens none.
 """
 
 from __future__ import annotations
@@ -135,17 +134,6 @@ class _Span:
         self.args.update(kwargs)
         return self
 
-    def since(self, t0_us: float) -> "_Span":
-        """Backdates the open span to ``t0_us`` (an earlier ``_now_us()``),
-        for work known to deserve a span only once it has run. The
-        profiler cannot annotate the past, so the span's annotation is
-        closed here."""
-        self._t0 = t0_us
-        if self._anno is not None:
-            self._anno.__exit__(None, None, None)
-            self._anno = None
-        return self
-
     def __enter__(self) -> "_Span":
         stack = self._tracer._stack()
         if stack:
@@ -193,9 +181,6 @@ class _NullSpan:
         self.args: Dict = {}
 
     def set(self, **kwargs) -> "_NullSpan":
-        return self
-
-    def since(self, t0_us: float) -> "_NullSpan":
         return self
 
     def __enter__(self) -> "_NullSpan":

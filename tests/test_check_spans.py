@@ -1,7 +1,8 @@
 """Per-check tracing inside the device checker (CPU): every span of a check
 carries its check id and a parent in the check's tree, executable
-acquisitions are spans, path rebuilding counts its work, and the drain's
-lowered program names its stages."""
+acquisitions are spans, checks under one AOT namespace and signature share
+every program, path rebuilding counts its work, and the drain's lowered
+program names its stages."""
 
 import threading
 
@@ -9,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from stateright_tpu.models.paxos import PaxosModelCfg
 from stateright_tpu.models.two_phase_commit import TwoPhaseSys
 from stateright_tpu.ops.hashset import hashset_new
 from stateright_tpu.telemetry import get_tracer
@@ -135,11 +137,95 @@ def test_second_check_obtains_executables_without_compiling(two_checks):
     for e in exes:
         assert e["args"]["source"] in ("memory", "persistent_cache"), e
         assert e["args"]["trace_ms"] >= 0 and e["args"]["build_ms"] >= 0
+    # Every program, of every kind, comes from the shared AOT cache: no
+    # trace, no compile and no load.
+    for e in exes:
+        assert e["args"]["source"] == "memory", e
+        assert e["args"]["trace_ms"] == 0 and e["args"]["build_ms"] == 0, e
     drains = [e for e in exes if e["args"]["kind"] == "drain"]
-    assert drains and all(e["args"]["source"] == "memory" for e in drains)
     # A call that reuses an executable opens no span: one acquisition per
     # (function, shape) in a check.
-    assert len(_spans(second, "tpu_bfs.drain")) > len(drains)
+    assert drains and len(_spans(second, "tpu_bfs.drain")) > len(drains)
+
+
+def answers(checker):
+    """Counts, depth, and each discovery's path length."""
+    return (
+        checker.unique_state_count(),
+        checker.state_count(),
+        checker.max_depth(),
+        {name: len(path) for name, path in checker.discoveries().items()},
+    )
+
+
+def test_shared_cache_check_answers_as_the_host_bfs(two_checks):
+    host = TwoPhaseSys(3).checker().spawn_bfs().join()
+    for checker, _ in two_checks:
+        assert answers(checker) == answers(host)
+
+
+def test_fps_checks_share_every_program():
+    """Paxos on the fps wave, one wave a dispatch on a bucket ladder, to
+    depth 9: the second check under one namespace takes ``materialize``,
+    ``compact`` and the wave from the cache too, and answers as the host
+    ``BfsChecker``."""
+    spawn = dict(
+        frontier_capacity=64, table_capacity=1 << 12, max_drain_waves=1,
+        bucket_ladder=2, expand_fps=True, aot_cache="test_check_spans.fps",
+    )
+
+    def builder():
+        return PaxosModelCfg(2, 3).into_model().checker().target_max_depth(9)
+
+    rec = _Recorder()
+    get_tracer().add_sink(rec)
+    try:
+        checks = []
+        for _ in range(2):
+            start = len(rec.events)
+            checker = builder().spawn_tpu_bfs(**spawn).join()
+            checks.append((checker, _spans(rec.events[start:], "tpu_bfs.exe")))
+    finally:
+        get_tracer().remove_sink(rec, close=False)
+    host = builder().spawn_bfs().join()
+    (first, built), (second, exes) = checks
+    assert first._use_fps and len(first._buckets) > 1
+    kinds = {e["args"]["kind"] for e in built}
+    assert {"materialize", "compact", "wave", "init", "take"} <= kinds
+    assert {e["args"]["kind"] for e in exes} == kinds
+    assert {e["args"]["source"] for e in exes} == {"memory"}
+    assert second._prog_exec is first._prog_exec
+    assert answers(first) == answers(second) == answers(host)
+
+
+def test_signatures_under_one_namespace_do_not_share(two_checks):
+    rec = _Recorder()
+    get_tracer().add_sink(rec)
+    try:
+        other = TwoPhaseSys(4).checker().spawn_tpu_bfs(**SPAWN).join()
+    finally:
+        get_tracer().remove_sink(rec, close=False)
+    assert other.unique_state_count() == 1568
+    assert other._prog_exec is not two_checks[0][0]._prog_exec
+    exes = _spans(rec.events, "tpu_bfs.exe")
+    assert exes and all(e["args"]["source"] != "memory" for e in exes)
+
+
+def test_checker_without_aot_cache_shares_nothing(two_checks):
+    spawn = {k: v for k, v in SPAWN.items() if k != "aot_cache"}
+    rec = _Recorder()
+    get_tracer().add_sink(rec)
+    try:
+        lone = TwoPhaseSys(3).checker().spawn_tpu_bfs(**spawn).join()
+    finally:
+        get_tracer().remove_sink(rec, close=False)
+    assert lone.unique_state_count() == 288
+    shared = two_checks[0][0]
+    for name in ("_prog_exec", "_drain_exec", "_wave_exec"):
+        assert getattr(lone, name) is not getattr(shared, name), name
+    exes = _spans(rec.events, "tpu_bfs.exe")
+    assert {"init", "drain"} <= {e["args"]["kind"] for e in exes}
+    assert all(e["args"]["source"] != "memory" for e in exes)
 
 
 def test_drain_exits_count_log_bytes_and_refills(two_checks):

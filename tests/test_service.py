@@ -14,6 +14,7 @@ from stateright_tpu import WriteReporter
 from stateright_tpu.models.two_phase_commit import TwoPhaseSys
 from stateright_tpu.service import CheckService, ServiceServer
 from stateright_tpu.telemetry import metrics_registry
+from stateright_tpu.utils.faults import FaultSpec, inject
 
 # Shapes shared with the rest of the suite, so the persistent compile
 # cache keeps these tests cheap.
@@ -73,18 +74,29 @@ def test_concurrent_jobs_preempt_and_stay_exact():
     the device at wave granularity; both verdicts match the batch path
     exactly and their golden reports agree with each other (identical
     workload). Packed co-scheduling of the same pair is covered by
-    tests/test_packed_tenancy.py."""
+    tests/test_packed_tenancy.py.
+
+    Contention does not hang on the machine's speed: every pass of a
+    job's drain loop is held 50 ms (an injected ``wave.stall``), and
+    a 2pc-4 job makes more passes than one quantum holds, so neither job
+    can finish inside its first slice whatever its set-up costs."""
+    stall_s = 0.05
     svc = CheckService(
         quantum_s=0.75, default_spawn=dict(SPAWN_2PC), packing=False
     )
     try:
-        h1 = svc.submit(model_name="2pc", model_args={"rm_count": 4})
-        h2 = svc.submit(model_name="2pc", model_args={"rm_count": 4})
-        r1 = h1.result(timeout=300)
-        r2 = h2.result(timeout=300)
+        with inject(
+            FaultSpec("wave.stall", count=1 << 30, stall_s=stall_s)
+        ) as inj:
+            h1 = svc.submit(model_name="2pc", model_args={"rm_count": 4})
+            h2 = svc.submit(model_name="2pc", model_args={"rm_count": 4})
+            r1 = h1.result(timeout=300)
+            r2 = h2.result(timeout=300)
         assert r1["unique"] == UNIQUE_2PC4
         assert r2["unique"] == UNIQUE_2PC4
         assert _golden(r1["report"]) == _golden(r2["report"])
+        # Each job's passes alone outlast a quantum.
+        assert inj.hits("wave.stall") * stall_s > 2 * 2 * svc.quantum_s
         # Contention existed, so at least one job was preempted mid-run —
         # and its result is still exact (the bit-identical guarantee under
         # real scheduling, not just the direct-API test).

@@ -192,33 +192,46 @@ def test_profiler_bridge_follows_enablement(monkeypatch):
 
 
 def test_traced_jit_records_only_calls_that_obtain_an_executable():
-    import jax
+    """``AotJit`` keys each call on its static arguments and the avals of
+    the others, and calls the executable its ``obtain`` supplies without
+    the static ones; an ``exe_span`` around a miss records where the
+    executable came from."""
     import jax.numpy as jnp
 
-    from stateright_tpu.telemetry.executables import TracedJit
+    from stateright_tpu.telemetry.executables import AotJit, exe_span
 
     tracer = Tracer()
-    fn = TracedJit(jax.jit(lambda x: x + 1), tracer, "t.exe", "inc")
+    exes, keys = {}, []
+
+    def obtain(kind, key, jit_fn, args):
+        keys.append(key)
+        full = (kind,) + key
+        if full not in exes:
+            with exe_span(tracer, "t.exe", kind):
+                exes[full] = jit_fn.lower(*args).compile()
+        return exes[full]
+
+    fn = AotJit(obtain, "head", lambda x, n: x[:n] + 1, static_argnums=(1,))
     with tracer.span("outer") as outer:
-        t0 = time.perf_counter_ns() / 1e3
-        fn(jnp.zeros(3))
-        t1 = time.perf_counter_ns() / 1e3
-        fn(jnp.ones(3))  # same signature: reused, no span
-        fn(jnp.zeros(5))  # new shape: a second acquisition
-    exes = [e for e in tracer.events() if e["name"] == "t.exe"]
-    assert len(exes) == 2
-    first = exes[0]
-    # Backdated to the call's start, inside the span open around it.
-    assert t0 <= first["ts"] and first["ts"] + first["dur"] <= t1
+        assert list(fn(jnp.zeros(3), 2)) == [1, 1]
+        assert list(fn(jnp.ones(3), 2)) == [2, 2]  # same key: reused
+        fn(jnp.zeros(5), 2)  # new shape: a second executable
+        fn(jnp.zeros(5), 1)  # new static argument: a third
+        fn(jnp.zeros(5, jnp.int32), 1)  # new dtype: a fourth
+    assert keys[0] == keys[1] and len(set(keys)) == 4
+    assert keys[0][0] == (2,)
+    spans = [e for e in tracer.events() if e["name"] == "t.exe"]
+    assert len(spans) == 4
+    first = spans[0]
     assert first["parent"] == outer.id
-    assert first["args"]["kind"] == "inc"
-    assert first["args"]["source"] in ("memory", "persistent_cache", "compile")
-    assert first["args"]["trace_ms"] >= 0 and first["args"]["build_ms"] >= 0
-    tracer.enabled = False
-    fn(jnp.zeros(7))
-    tracer.enabled = True
-    fn(jnp.zeros(7))  # obtained while disabled: held, so no span now
-    assert len([e for e in tracer.events() if e["name"] == "t.exe"]) == 2
+    assert first["args"]["kind"] == "head"
+    assert first["args"]["source"] in ("persistent_cache", "compile")
+    assert first["args"]["trace_ms"] > 0 and first["args"]["build_ms"] >= 0
+    # A Python scalar is weakly typed, an int32 array is not: two keys.
+    inc = AotJit(obtain, "inc", lambda x: x + 1)
+    inc(1)
+    inc(jnp.int32(1))
+    assert keys[-1] != keys[-2]
 
 
 def test_jsonl_sink_and_chrome_export(tmp_path):
