@@ -944,7 +944,7 @@ def _run_service_leg(pin_cpu: bool, packed: bool = False):
         import shutil
         import tempfile
 
-        from stateright_tpu.checker.tpu import clear_shared_aot_caches
+        from stateright_tpu.checker.programs import clear_shared_aot_caches
 
         wdir = tempfile.mkdtemp(prefix="bench-warmstart-")
 

@@ -63,7 +63,6 @@ from __future__ import annotations
 
 import itertools
 import threading
-import time
 from collections import deque
 from typing import Dict, List, Optional
 
@@ -86,6 +85,7 @@ from ..telemetry import (
 from ..utils.faults import TenantFaultError, fault_point
 from .base import Checker
 from .pipeline import HostPipeline
+from .programs import Programs
 from .tpu import (
     _DEPTH_INF,
     _MAX_LOAD,
@@ -94,7 +94,6 @@ from .tpu import (
     bucket_ladder_widths,
     checkpoint_header,
     packed_model_digest,
-    shared_aot_cache,
     validate_checkpoint_header,
 )
 
@@ -448,15 +447,13 @@ class TenantPackedEngine:
         self._state_treedef = treedef
         self._leaf_specs = [(x.shape[1:], x.dtype) for x in leaves]
 
-        # Executables: (kind, *shape) -> AOT-compiled fn; process-global
-        # under a namespace so engines for one pack config never
-        # recompile.
-        if aot_cache is not None:
-            self._exec = shared_aot_cache(
-                aot_cache, ("packed_tenancy",) + self._aot_signature()
-            )
-        else:
-            self._exec = {}
+        # Executables by (kind, *shape); shared process-wide under a
+        # namespace, so engines for one pack config never recompile.
+        self._programs = Programs(
+            "pack", self._tracer, signature=self._aot_signature(),
+            aot_cache=aot_cache, registry=self._registry,
+            on_compile=self._charge_compile,
+        )
         self._jit_wave = jax.jit(self._wave, donate_argnums=(0,))
         self._jit_seed = jax.jit(self._seed_wave, donate_argnums=(0,))
         self._jit_bulk = jax.jit(self._bulk_insert, donate_argnums=(0,))
@@ -675,17 +672,9 @@ class TenantPackedEngine:
 
     # -- AOT dispatch ------------------------------------------------------
 
-    def _compiled(self, kind, jit_fn, args, key_extra=()):
-        key = (kind,) + tuple(key_extra)
-        exe = self._exec.get(key)
-        if exe is None:
-            t0 = time.perf_counter()
-            with self._tracer.span("pack.compile", kind=kind):
-                exe = jit_fn.lower(*args).compile()
-            self._exec[key] = exe
-            self.compile_seconds += time.perf_counter() - t0
-            self._wi.warmup.set(self.compile_seconds)
-        return exe
+    def _charge_compile(self, kind, seconds):
+        self.compile_seconds += seconds
+        self._wi.warmup.set(self.compile_seconds)
 
     # -- membership --------------------------------------------------------
 
@@ -774,10 +763,9 @@ class TenantPackedEngine:
         n_unique = 0
         attempt = 0
         while True:
-            exe = self._compiled(
-                "seed", self._jit_seed,
+            exe = self._programs.obtain(
+                "seed", (self._table.shape[0],), self._jit_seed,
                 (self._table, jnp.uint32(t.salt_hi), jnp.uint32(t.salt_lo)),
-                (self._table.shape[0],),
             )
             out = exe(
                 self._table, jnp.uint32(t.salt_hi), jnp.uint32(t.salt_lo)
@@ -883,9 +871,8 @@ class TenantPackedEngine:
                     jnp.uint32(t.salt_lo),
                     jnp.asarray(act),
                 )
-                exe = self._compiled(
-                    "bulk", self._jit_bulk, args,
-                    (self._table.shape[0],),
+                exe = self._programs.obtain(
+                    "bulk", (self._table.shape[0],), self._jit_bulk, args
                 )
                 self._table, fresh_n, pend = exe(*args)
                 self._l0 += int(fresh_n)
@@ -1016,9 +1003,9 @@ class TenantPackedEngine:
             capacity *= 2
         while True:
             args = (self._table, hashset_new(capacity))
-            exe = self._compiled(
-                "rehash", self._jit_rehash, args,
-                (self._table.shape[0], capacity),
+            exe = self._programs.obtain(
+                "rehash", (self._table.shape[0], capacity), self._jit_rehash,
+                args,
             )
             with self._tracer.span(
                 "pack.table_grow", from_capacity=self._capacity,
@@ -1328,9 +1315,8 @@ class TenantPackedEngine:
                 jnp.asarray(sl),
                 jnp.asarray(dc),
             )
-            exe = self._compiled(
-                "wave", self._jit_wave, args,
-                (self._table.shape[0], width),
+            exe = self._programs.obtain(
+                "wave", (self._table.shape[0], width), self._jit_wave, args
             )
             # Injection seam: a packed device-wave raise is inherently
             # engine-wide (every tenant's lanes ride the one dispatch)

@@ -65,10 +65,10 @@ from ..ops.hashset import (
 )
 from ..ops.ring import ring_export, ring_push, ring_rows, ring_take
 from ..telemetry import CheckTracer, WaveInstruments, metrics_registry
-from ..telemetry.executables import AotJit, exe_span
 from ..utils.faults import fault_point
 from .base import _NULL_CTX, Checker  # noqa: F401 - _NULL_CTX re-exported
 from .pipeline import HostPipeline
+from .programs import CUSTOM_REP_SCHEME, STEPS, Programs
 
 _DEPTH_INF = (1 << 31) - 1
 _U32_MAX = np.uint32(0xFFFFFFFF)  # numpy: keeps module import backend-free
@@ -226,9 +226,6 @@ def atomic_pickle(path, payload) -> None:
 # different (still orbit-proper) key space, so symmetry checkpoints
 # record and validate this tag.
 SYM_KEY_SCHEME = "wl-canon+orbitmin-v2"
-# Custom ``symmetry_fn`` runs key on fp(model.packed_representative(s)) —
-# a third key space, tagged separately in checkpoints.
-CUSTOM_REP_SCHEME = "custom-representative-v1"
 
 
 def sym_key_scheme(symmetry) -> "Optional[str]":
@@ -502,36 +499,6 @@ def compact(chunk, width):
     return out
 
 
-# -- cross-checker AOT executable sharing (checking-as-a-service) -----------
-#
-# One resident process serving many jobs must never recompile a wave shape
-# a previous job already built: the wave/drain executables are pure XLA
-# programs (model constants baked in at trace time), so two checker
-# INSTANCES whose traces are provably identical can share them. "Provably"
-# is the caller's namespace (e.g. the service's model-zoo entry name)
-# ANDed with a full trace signature — model digest, property list, key
-# scheme, pipeline, ladder, capacities — so a namespace collision between
-# genuinely different configurations still misses instead of corrupting.
-_AOT_LOCK = threading.Lock()
-_AOT_CACHES: Dict[tuple, dict] = {}
-
-
-def shared_aot_cache(namespace: str, signature: tuple) -> dict:
-    """The process-global executable dict for one (namespace, signature)
-    — get-or-create, so every checker spawned with the same
-    ``aot_cache=namespace`` and an identical trace signature probes and
-    populates the same cache."""
-    key = (namespace, signature)
-    with _AOT_LOCK:
-        return _AOT_CACHES.setdefault(key, {})
-
-
-def clear_shared_aot_caches() -> None:
-    """Drops every shared executable (tests / memory reclamation)."""
-    with _AOT_LOCK:
-        _AOT_CACHES.clear()
-
-
 class TpuBfsChecker(Checker):
     """Requires the model to implement ``BatchableModel``.
 
@@ -608,9 +575,6 @@ class TpuBfsChecker(Checker):
         self.run_id = run_id
         self._registry = metrics_registry(run_id) if run_id else None
         self._tracer = CheckTracer(run_id)
-        # Executables this check already holds, by (kind,) + key: a later
-        # lookup of one is a reuse, not an acquisition.
-        self._exe_held = set()
         self._drains = 0
         self._fp_calls = 0
         self._conditions = model.packed_conditions()
@@ -1065,67 +1029,21 @@ class TpuBfsChecker(Checker):
             self._jit_wave = jax.jit(_wave_live, donate_argnums=(0, 1))
         else:
             self._jit_wave = jax.jit(self._wave, donate_argnums=(0,))
-        # (bucket width, table capacity) -> AOT-compiled wave: the ladder
-        # rungs and table growths each compile once, steady state replays.
-        self._wave_exec = {}
-        # Deep-drain executables, one per ladder rung actually visited:
-        # ``_drain_jits`` holds the width-closed jit objects, ``_drain_exec``
-        # the AOT-compiled executables keyed (width, table rows, pool
-        # capacity) — compiles are lazy, so a run that never leaves F_max
-        # pays for exactly one drain compile.
+        # Every program runs as an AOT executable from ``_programs``: the
+        # wave keyed (table rows, bucket width), the deep drain (width,
+        # table rows, pool capacity) — one per ladder rung visited, so a
+        # run that never leaves F_max compiles one drain — and the smaller
+        # programs (kind, static args, argument avals). ``_drain_jits``
+        # holds the width-closed drain jit objects.
         self._drain_jits = {}
-        self._drain_exec = {}
-        # Every smaller program (pool, seed, frontier slicing, table
-        # growth, fingerprints) runs as an AOT executable from _obtain,
-        # keyed (kind, static args, argument avals).
-        self._prog_exec = {}
-        # Cross-job sharing: with ``aot_cache="<namespace>"`` the three
-        # executable dicts come from the process-global cache instead, so
-        # same-shaped programs across checker instances (the service's
-        # jobs, a preempted job's resumed incarnation) never retrace. The
-        # namespace asserts semantic equivalence the trace signature
-        # cannot see (e.g. property conditions closing over model fields
-        # outside the packed arrays); the signature guards everything it
-        # can see, so a namespace reuse across different shapes/configs
-        # misses instead of corrupting.
-        if aot_cache is not None:
-            if self._sym_scheme == CUSTOM_REP_SCHEME:
-                raise ValueError(
-                    "aot_cache cannot be shared under a custom "
-                    "symmetry_fn: the traced key function is caller "
-                    "code the cache signature cannot compare"
-                )
-            sig = self._aot_signature()
-            self._wave_exec = shared_aot_cache(aot_cache, ("wave",) + sig)
-            self._drain_exec = shared_aot_cache(aot_cache, ("drain",) + sig)
-            self._prog_exec = shared_aot_cache(aot_cache, ("prog",) + sig)
-        # Disk tier of the AOT cache (warm-start plane): serialized
-        # executables persist under the service dir, fenced on
-        # jax-version/backend/topology so a fresh PROCESS serves its
-        # first job compile-free. Probed only on in-memory misses; a
-        # disk hit bypasses the compile phase entirely (the attribution
-        # ledger records zero compile), a refused entry is a miss.
-        self._aot_disk = None
-        if aot_store is not None:
-            if aot_cache is None:
-                raise ValueError(
-                    "aot_store requires aot_cache=<namespace>: the disk "
-                    "entries inherit the namespace's semantic-equivalence "
-                    "assertion (see shared_aot_cache)"
-                )
-            from ..storage.persist import AotDiskStore
-
-            store = (
-                aot_store
-                if isinstance(aot_store, AotDiskStore)
-                else AotDiskStore(aot_store)
-            )
-            self._aot_disk = store.binding(
-                aot_cache, self._aot_signature(), registry=self._registry
-            )
-        def aot(kind, fn, **jit_kwargs):
-            return AotJit(self._obtain, kind, fn, **jit_kwargs)
-
+        self._programs = Programs(
+            "tpu_bfs", self._tracer, signature=self._aot_signature(),
+            aot_cache=aot_cache, aot_store=aot_store,
+            registry=self._registry,
+            compile_phase=lambda: self._phase("compile"),
+            on_compile=self._charge_compile,
+        )
+        aot = self._programs.jit
         self._jit_pool_zero = aot(
             "pool_zero", self._pool_zero, static_argnums=(0,)
         )
@@ -2139,13 +2057,10 @@ class TpuBfsChecker(Checker):
         if self._live_enabled:
             args = (table, self._elog) + args[1:]
         key = (table.shape[0], chunk["hi"].shape[0])
-        exe = self._wave_exec.get(key)
-        if exe is None or ("wave",) + key not in self._exe_held:
-            exe = self._obtain_wave(key, exe, args)
-        elif self._aot_disk is not None:
-            # Warm-memory / cold-disk: backfill so a later fresh process
-            # still finds the artifact (one existence probe per key).
-            self._aot_disk.ensure("wave", key, exe)
+        exe = self._programs.obtain(
+            "wave", key, self._jit_wave, args,
+            table_capacity=key[0], frontier=key[1],
+        )
         if self._attr is None:
             out = exe(*args)
         else:
@@ -2935,152 +2850,39 @@ class TpuBfsChecker(Checker):
         )
         return newq
 
-    def _obtain(self, kind, key, jit_fn, args):
-        """The compiled ``jit_fn`` for ``args`` under ``(kind,) + key``: the
-        one this check holds, else the shared AOT cache's (or, without
-        ``aot_cache``, this checker's own), else one lowered and compiled
-        now, through the persistent compilation cache. Each first use in a
-        check is one ``tpu_bfs.exe`` span, ``source`` ``memory`` on a cache
-        hit; a compile here is not added to ``warmup_seconds``."""
-        full = (kind,) + key
-        exe = self._prog_exec.get(full)
-        if exe is not None and full in self._exe_held:
-            return exe
-        with exe_span(self._tracer, "tpu_bfs.exe", kind):
-            if exe is None:
-                exe = jit_fn.lower(*args).compile()
-                self._prog_exec[full] = exe
-        self._exe_held.add(full)
-        return exe
-
-    def _obtain_wave(self, key, exe, args):
-        """A wave executable this check does not hold yet, keyed on (table
-        rows, bucket width): ``exe`` from the shared AOT cache, else the
-        disk AOT store, else a compile (see ``_call_wave``)."""
-        with exe_span(
-            self._tracer, "tpu_bfs.exe", "wave",
-            table_capacity=key[0], frontier=key[1],
-        ) as sp:
-            if exe is not None:
-                sp.set(source="memory")
-                if self._aot_disk is not None:
-                    self._aot_disk.ensure("wave", key, exe)
-            elif self._aot_disk is not None:
-                # Disk tier of the AOT cache: a fenced hit deserializes
-                # the executable outside the compile phase and span — the
-                # whole point is that the attribution ledger records no
-                # compile.
-                t0 = time.perf_counter()
-                exe = self._aot_disk.load("wave", key)
-                if exe is not None:
-                    self._wave_exec[key] = exe
-                    sp.set(
-                        source="disk",
-                        build_ms=(time.perf_counter() - t0) * 1e3,
-                    )
-            if exe is None:
-                t0 = time.perf_counter()
-                # AOT-cache miss == a compile is about to happen: the ONE
-                # place the attribution engine can detect first-dispatch
-                # rung compiles (the cache hit path never enters here).
-                with self._tracer.span(
-                    "tpu_bfs.compile", table_capacity=key[0],
-                    frontier=key[1],
-                ), self._phase("compile"):
-                    lowered = self._jit_wave.lower(*args)
-                    t1 = time.perf_counter()
-                    exe = lowered.compile()
-                sp.set(
-                    trace_ms=(t1 - t0) * 1e3,
-                    build_ms=(time.perf_counter() - t1) * 1e3,
-                )
-                self._wave_exec[key] = exe
-                if self.warmup_seconds is not None:
-                    self.warmup_seconds += time.perf_counter() - t0
-                    self._wi.warmup.set(self.warmup_seconds)
-                if self._aot_disk is not None:
-                    self._aot_disk.save("wave", key, exe)
-        self._exe_held.add(("wave",) + key)
-        return exe
+    def _charge_compile(self, kind, seconds):
+        """A wave or drain compile after the warm-up stamp is added to
+        ``warmup_seconds``, keeping the steady-state window honest; the
+        smaller programs' compiles are not."""
+        if kind in STEPS and self.warmup_seconds is not None:
+            self.warmup_seconds += seconds
+            self._wi.warmup.set(self.warmup_seconds)
 
     def _drain_exe(self, width, args, t_start):
-        """The AOT-compiled deep drain for one ladder rung, keyed on
-        (width, table rows, pool capacity) so table/ring growth recompiles
-        are explicit and measured. The first compile stamps warmup; later
-        compiles (new rung or grown shapes) are added to it, keeping the
-        steady-state window honest."""
-        key = (width, args[0].shape[0], self._pool_capacity)
-        exe = self._drain_exec.get(key)
-        if exe is not None and ("drain",) + key in self._exe_held:
-            if self._aot_disk is not None:
-                # Warm-memory / cold-disk backfill, same as the wave site.
-                self._aot_disk.ensure("drain", key, exe)
-            return exe
-        with exe_span(
-            self._tracer, "tpu_bfs.exe", "drain",
-            bucket=width, table_capacity=key[1],
-        ) as sp:
-            if exe is not None:
-                sp.set(source="memory")
-                if self._aot_disk is not None:
-                    self._aot_disk.ensure("drain", key, exe)
-            elif self._aot_disk is not None:
-                # Disk tier (warm-start plane): a fenced hit loads the
-                # rung outside the compile phase — cross-process warm
-                # starts record zero compile, exactly like the in-memory
-                # hit above.
-                t0 = time.perf_counter()
-                exe = self._aot_disk.load("drain", key)
-                if exe is not None:
-                    self._drain_exec[key] = exe
-                    sp.set(
-                        source="disk",
-                        build_ms=(time.perf_counter() - t0) * 1e3,
-                    )
-            if exe is not None and self.warmup_seconds is None:
-                # Warm start (shared AOT cache hit on the very first
-                # drain): stamp the setup-only warmup now. Leaving it None
-                # would both under-report the warm/steady split and make
-                # the service's stall watchdog treat the whole run as
-                # warmup (its pet condition defers to an unstamped
-                # warmup).
-                self._set_warmup(time.perf_counter() - t_start)
-            if exe is None:
-                jit_fn = self._drain_jits.get(width)
-                if jit_fn is None:
+        """The AOT-compiled deep drain for one ladder rung. The first drain
+        stamps the warm-up (compiled or not: leaving it None would make
+        the service's stall watchdog treat the whole run as warm-up);
+        later compiles (new rung or grown shapes) are added to it."""
+        jit_fn = self._drain_jits.get(width)
+        if jit_fn is None:
 
-                    def fn(*a, _w=width):
-                        return self._deep_drain(_w, *a)
+            def fn(*a, _w=width):
+                return self._deep_drain(_w, *a)
 
-                    # The executable's name in device traces, one per
-                    # ladder rung (``jit(fn)`` otherwise).
-                    fn.__name__ = fn.__qualname__ = f"tpu_bfs_drain_w{width}"
-                    donate = (0, 1, 7) if self._live_enabled else (0, 1)
-                    jit_fn = jax.jit(fn, donate_argnums=donate)
-                    self._drain_jits[width] = jit_fn
-                t0 = time.perf_counter()
-                # AOT-cache miss: the drain rung is about to compile — the
-                # attribution engine's compile-detection site for drains.
-                with self._tracer.span(
-                    "tpu_bfs.compile", kind="drain", bucket=width,
-                    table_capacity=key[1],
-                ), self._phase("compile"):
-                    lowered = jit_fn.lower(*args)
-                    t1 = time.perf_counter()
-                    exe = lowered.compile()
-                sp.set(
-                    trace_ms=(t1 - t0) * 1e3,
-                    build_ms=(time.perf_counter() - t1) * 1e3,
-                )
-                self._drain_exec[key] = exe
-                if self.warmup_seconds is None:
-                    self._set_warmup(time.perf_counter() - t_start)
-                else:
-                    self.warmup_seconds += time.perf_counter() - t0
-                    self._wi.warmup.set(self.warmup_seconds)
-                if self._aot_disk is not None:
-                    self._aot_disk.save("drain", key, exe)
-        self._exe_held.add(("drain",) + key)
+            # The executable's name in device traces, one per ladder rung
+            # (``jit(fn)`` otherwise).
+            fn.__name__ = fn.__qualname__ = f"tpu_bfs_drain_w{width}"
+            donate = (0, 1, 7) if self._live_enabled else (0, 1)
+            jit_fn = self._drain_jits[width] = jax.jit(
+                fn, donate_argnums=donate
+            )
+        table_rows = args[0].shape[0]
+        exe = self._programs.obtain(
+            "drain", (width, table_rows, self._pool_capacity), jit_fn, args,
+            bucket=width, table_capacity=table_rows,
+        )
+        if self.warmup_seconds is None:
+            self._set_warmup(time.perf_counter() - t_start)
         return exe
 
     def _export_pool_chunks(self, pool, head, count):

@@ -36,7 +36,6 @@ allgather; checkpoints written by process 0). Exercised end-to-end on a
 
 from __future__ import annotations
 
-import contextlib
 import threading
 import time
 from collections import deque
@@ -62,22 +61,20 @@ from ..telemetry import (
     WaveInstruments,
     metrics_registry,
 )
-from ..telemetry.executables import exe_span
 from .base_mesh import default_mesh
 from ..checker.base import Checker
 from ..checker.pipeline import HostPipeline
+from ..checker.programs import Programs
 from ..utils.faults import fault_point
 from ..checker.tpu import (
     _AUTO_BUCKET_MIN_F,
     _DEFAULT_BUCKET_STEPS,
-    CUSTOM_REP_SCHEME,
     _make_key_fn,
     atomic_pickle,
     bucket_for,
     bucket_ladder_widths,
     checkpoint_header,
     packed_model_digest,
-    shared_aot_cache,
     sym_key_scheme,
     validate_checkpoint_header,
 )
@@ -107,31 +104,6 @@ def _sort_dedup(hi, lo, active):
         [jnp.ones((1,), bool), (shi[1:] != shi[:-1]) | (slo[1:] != slo[:-1])]
     )
     return shi, slo, sidx, active[sidx] & uniq
-
-
-class _ShardedJit:
-    """A program of one sharded checker, run as AOT executables that its
-    ``_obtain`` keys on the program's ``kind``, its first ``static``
-    arguments and the shapes and dtypes of the rest: checks under one
-    ``aot_cache`` namespace share them."""
-
-    __slots__ = ("_checker", "_kind", "_jit", "_static")
-
-    def __init__(self, checker, kind, jit_fn, static=0):
-        self._checker = checker
-        self._kind = kind
-        self._jit = jit_fn
-        self._static = static
-
-    def __call__(self, *args):
-        dyn = args[self._static:]
-        leaves, tree = jax.tree_util.tree_flatten(dyn)
-        key = (
-            args[: self._static],
-            tree,
-            tuple((tuple(x.shape), str(x.dtype)) for x in leaves),
-        )
-        return self._checker._obtain(self._kind, key, self._jit, args)(*dyn)
 
 
 class ShardedTpuBfsChecker(Checker):
@@ -469,65 +441,20 @@ class ShardedTpuBfsChecker(Checker):
             deep_in = deep_in + (P("fp"), P("fp"))
             deep_donate = (0, 1, 7, 8)
 
-        def sharded(fn, in_specs, **jit_kwargs):
-            return jax.jit(
-                jax.shard_map(
-                    fn,
-                    mesh=self._mesh,
-                    in_specs=in_specs,
-                    out_specs=P("fp"),
-                    check_vma=False,
-                ),
-                **jit_kwargs,
+        def local(fn, in_specs):
+            return jax.shard_map(
+                fn,
+                mesh=self._mesh,
+                in_specs=in_specs,
+                out_specs=P("fp"),
+                check_vma=False,
             )
 
-        def shared(kind, fn, in_specs, **jit_kwargs):
-            return _ShardedJit(
-                self, kind, sharded(fn, in_specs, **jit_kwargs)
-            )
-
-        # Every program runs as an AOT executable obtained by _obtain:
-        # this check's, the shared AOT cache's, the disk store's, or a
-        # compile. The wave and the deep drain are dispatched directly,
-        # the smaller programs through _ShardedJit.
-        self._jit_wave = sharded(
-            self._wave_local, wave_in, donate_argnums=wave_donate
+        self._jit_wave = jax.jit(
+            local(self._wave_local, wave_in), donate_argnums=wave_donate
         )
-        self._jit_deep_drain = sharded(
-            self._deep_drain_local, deep_in, donate_argnums=deep_donate
-        )
-        # Disk tier of the executable cache (warm-start plane,
-        # storage/persist.py): bound lazily at the first dispatch, once
-        # every trace-relevant attribute is set.
-        self._aot_store_arg = aot_store
-        self._aot_disk = None
-        self._jit_insert = shared(
-            "insert", self._insert_local, (P("fp"),) * 4, donate_argnums=(0,)
-        )
-        # Only the destination table (arg 1) can alias the output; the
-        # old, smaller table is freed by the caller's rebind.
-        self._jit_rehash = shared(
-            "rehash", self._rehash_local, (P("fp"), P("fp")),
-            donate_argnums=(1,),
-        )
-        self._jit_ring_push = shared(
-            "ring_push", self._push_local, (P("fp"),) * 4, donate_argnums=(0,)
-        )
-        self._jit_ring_export = shared(
-            "ring_export", self._ring_export_local, (P("fp"),) * 3
-        )
-        # Pre-sharded allocations (shape and dtype static).
-        self._jit_zeros = _ShardedJit(
-            self, "zeros",
-            jax.jit(jnp.zeros, static_argnums=(0, 1),
-                    out_shardings=self._shard),
-            static=2,
-        )
-        self._jit_pool = _ShardedJit(
-            self, "pool",
-            jax.jit(lambda rows: ring_rows(model, rows), static_argnums=0,
-                    out_shardings=self._shard),
-            static=1,
+        self._jit_deep_drain = jax.jit(
+            local(self._deep_drain_local, deep_in), donate_argnums=deep_donate
         )
         # Fingerprints go through the model's view hook (e.g. actor systems
         # exclude crash flags, mirroring the host state hash).
@@ -567,20 +494,6 @@ class ShardedTpuBfsChecker(Checker):
                 host_budget_mib=host_budget_mib,
             )
 
-        def shared_jit(kind, fn):
-            return _ShardedJit(self, kind, jax.jit(fn))
-
-        self._jit_fp_batch = shared_jit("fp_batch", jax.vmap(self._fp_fn))
-        self._jit_key_batch = (
-            shared_jit("key_batch", self._key_fn)
-            if self._symmetry_enabled
-            else self._jit_fp_batch
-        )
-        self._jit_fp_single = shared_jit("fp_single", self._fp_fn)
-        self._jit_within = shared_jit(
-            "within", jax.vmap(model.packed_within_boundary)
-        )
-
         # Telemetry: one span per host-visible wave/drain (see
         # stateright_tpu.telemetry); occupancy is global across shards.
         # (Tracer/registry already bound above — run_id-scoped when set.)
@@ -612,26 +525,55 @@ class ShardedTpuBfsChecker(Checker):
             symmetry=self._symmetry_enabled,
         )
         self.donation_enabled = True
-        # Compiled programs keyed (kind, shapes): waves by (table rows,
-        # chunk width), deep drains by (table rows, ring rows), the rest
-        # by their arguments. With ``aot_cache=<namespace>`` the dict is
-        # the process-global one (checker/tpu.py ``shared_aot_cache``)
-        # for this checker's trace signature, so checks of one
-        # configuration share every program, the drains compiled for
-        # grown tables included. ``_exe_held``: the keys this check has
-        # obtained (each first use is one ``sharded_bfs.exe`` span).
-        self._exec = {}
-        self._exe_held = set()
-        if aot_cache is not None:
-            if self._sym_scheme == CUSTOM_REP_SCHEME:
-                raise ValueError(
-                    "aot_cache cannot be shared under a custom "
-                    "symmetry_fn: the traced key function is caller "
-                    "code the cache signature cannot compare"
-                )
-            self._exec = shared_aot_cache(
-                aot_cache, ("sharded",) + self._aot_signature()
-            )
+        # Every program runs as an AOT executable from ``_programs``:
+        # waves keyed (table rows, chunk width), deep drains (table rows,
+        # ring rows), the rest by their arguments. Checks under one
+        # ``aot_cache`` share every program, the drains compiled for grown
+        # tables included.
+        self._programs = Programs(
+            "sharded_bfs", self._tracer, signature=self._aot_signature(),
+            aot_cache=aot_cache, aot_store=aot_store,
+            registry=self._registry,
+            compile_phase=lambda: self._phase("compile"),
+            on_compile=self._charge_compile,
+        )
+        aot = self._programs.jit
+        self._jit_insert = aot(
+            "insert", local(self._insert_local, (P("fp"),) * 4),
+            donate_argnums=(0,),
+        )
+        # Only the destination table (arg 1) can alias the output; the
+        # old, smaller table is freed by the caller's rebind.
+        self._jit_rehash = aot(
+            "rehash", local(self._rehash_local, (P("fp"), P("fp"))),
+            donate_argnums=(1,),
+        )
+        self._jit_ring_push = aot(
+            "ring_push", local(self._push_local, (P("fp"),) * 4),
+            donate_argnums=(0,),
+        )
+        self._jit_ring_export = aot(
+            "ring_export", local(self._ring_export_local, (P("fp"),) * 3)
+        )
+        # Pre-sharded allocations (shape and dtype static).
+        self._jit_zeros = aot(
+            "zeros", jnp.zeros, static_argnums=(0, 1),
+            out_shardings=self._shard,
+        )
+        self._jit_pool = aot(
+            "pool", lambda rows: ring_rows(model, rows), static_argnums=(0,),
+            out_shardings=self._shard,
+        )
+        self._jit_fp_batch = aot("fp_batch", jax.vmap(self._fp_fn))
+        self._jit_key_batch = (
+            aot("key_batch", self._key_fn)
+            if self._symmetry_enabled
+            else self._jit_fp_batch
+        )
+        self._jit_fp_single = aot("fp_single", self._fp_fn)
+        self._jit_within = aot(
+            "within", jax.vmap(model.packed_within_boundary)
+        )
 
         self._handles = [
             threading.Thread(target=self._run, name="sharded-tpu-bfs", daemon=True)
@@ -2199,75 +2141,15 @@ class ShardedTpuBfsChecker(Checker):
             self._fleet_on,
         )
 
-    def _aot_disk_binding(self):
-        """The disk AOT binding, built on first use, under
-        ``_aot_signature`` so a configuration drift misses instead of
-        loading the wrong executable."""
-        if self._aot_store_arg is None:
-            return None
-        if self._aot_disk is None:
-            from ..storage.persist import AotDiskStore
-
-            store = (
-                self._aot_store_arg
-                if isinstance(self._aot_store_arg, AotDiskStore)
-                else AotDiskStore(self._aot_store_arg)
-            )
-            self._aot_disk = store.binding(
-                f"sharded:{type(self._model).__name__}",
-                self._aot_signature(), registry=self._registry,
-            )
-        return self._aot_disk
-
-    def _obtain(self, kind, key, jit_fn, args):
-        """The compiled ``jit_fn`` for ``args`` under ``(kind,) + key``:
-        the one this check holds, else the shared AOT cache's, else (wave
-        and drain) the disk store's, else a compile. A compile while the
-        run explores is added to ``warmup_seconds``. Each first use in a
-        check is one ``sharded_bfs.exe`` span, ``source`` ``shared``,
-        ``disk``, ``compile`` or ``persistent_cache``."""
-        full = (kind,) + key
-        exe = self._exec.get(full)
-        if exe is not None and full in self._exe_held:
-            return exe
-        step = kind in ("wave", "drain")
-        disk = self._aot_disk_binding() if step else None
-        with exe_span(self._tracer, "sharded_bfs.exe", kind) as sp:
-            if step:
-                sp.set(table_capacity=key[0], width=key[1])
-            if exe is not None:
-                sp.set(source="shared")
-                if disk is not None:
-                    disk.ensure(kind, key, exe)
-            elif disk is not None:
-                t0 = time.perf_counter()
-                exe = disk.load(kind, key)
-                if exe is not None:
-                    self._exec[full] = exe
-                    sp.set(
-                        source="disk",
-                        build_ms=(time.perf_counter() - t0) * 1e3,
-                    )
-            if exe is None:
-                t0 = time.perf_counter()
-                # The attribution engine's compile-detection site for
-                # waves and drains: only a miss of every cache enters.
-                with (
-                    self._phase("compile") if step
-                    else contextlib.nullcontext()
-                ):
-                    exe = jit_fn.lower(*args).compile()
-                self._exec[full] = exe
-                if (
-                    getattr(self, "warmup_seconds", None) is not None
-                    and not self._done_event.is_set()
-                ):
-                    self.warmup_seconds += time.perf_counter() - t0
-                    self._wi.warmup.set(self.warmup_seconds)
-                if disk is not None:
-                    disk.save(kind, key, exe)
-        self._exe_held.add(full)
-        return exe
+    def _charge_compile(self, kind, seconds):
+        """A compile while the run explores (after the warm-up stamp) is
+        added to ``warmup_seconds``."""
+        if (
+            getattr(self, "warmup_seconds", None) is not None
+            and not self._done_event.is_set()
+        ):
+            self.warmup_seconds += seconds
+            self._wi.warmup.set(self.warmup_seconds)
 
     def _call_wave(self, table, dev, depth_cap):
         """Wave through an AOT-compiled executable (keyed by local table
@@ -2291,8 +2173,10 @@ class ShardedTpuBfsChecker(Checker):
         )
         if self._sieve:
             args = args + self._sieve_dev
-        exe = self._obtain(
-            "wave", (table.shape[1], dev["hi"].shape[0]), self._jit_wave, args
+        key = (table.shape[1], dev["hi"].shape[0])
+        exe = self._programs.obtain(
+            "wave", key, self._jit_wave, args,
+            table_capacity=key[0], width=key[1],
         )
         if self._attr is None:
             out = exe(*args)
@@ -2489,9 +2373,10 @@ class ShardedTpuBfsChecker(Checker):
                     args = args + self._sieve_dev
                 # Obtained before the first drain runs (which may run the
                 # whole exploration), so no compile folds into it.
-                exe = self._obtain(
-                    "drain", (table.shape[1], self._PCl),
-                    self._jit_deep_drain, args,
+                key = (table.shape[1], self._PCl)
+                exe = self._programs.obtain(
+                    "drain", key, self._jit_deep_drain, args,
+                    table_capacity=key[0], width=key[1],
                 )
                 if self.warmup_seconds is None:
                     self.warmup_seconds = time.perf_counter() - self._t_start

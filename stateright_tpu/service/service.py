@@ -25,7 +25,7 @@ ways:
   ``preemptible: false`` in ``status()`` instead of being discovered
   from a swallowed NotImplementedError.
 
-Jobs multiplex onto the shared AOT rung cache (``checker/tpu.py``'s
+Jobs multiplex onto the shared AOT rung cache (``checker/programs.py``'s
 ``shared_aot_cache``): two jobs of the same zoo configuration share every
 ``(bucket, table_capacity)`` wave/drain executable (the packed engine
 shares its wave/seed/rehash executables the same way), so the second job
@@ -1417,16 +1417,10 @@ class CheckService:
         # Cross-job executable sharing (both device checkers).
         if job.aot_namespace is not None:
             spawn.setdefault("aot_cache", job.aot_namespace)
-        # Persistent AOT plane: the disk store rides along wherever a
-        # checker can use it — the solo checker needs a namespace (its
-        # in-memory shared cache keys on it); the sharded checker derives
-        # its own namespace internally.
-        if self.aot_store is not None:
-            if (
-                self.spawn_method == "spawn_tpu_bfs"
-                and spawn.get("aot_cache") is not None
-            ) or self.spawn_method == "spawn_sharded_tpu_bfs":
-                spawn.setdefault("aot_store", self.aot_store)
+        # Persistent AOT plane: the disk store rides along wherever the
+        # namespace it keys on is set.
+        if self.aot_store is not None and spawn.get("aot_cache") is not None:
+            spawn.setdefault("aot_store", self.aot_store)
         if job.hbm_budget_mib is not None:
             spawn.setdefault("hbm_budget_mib", job.hbm_budget_mib)
         if job.payload is None:

@@ -3,7 +3,7 @@
 Each entry is a factory taking JSON-friendly kwargs and returning a
 ``BatchableModel``. The zoo doubles as the AOT-cache namespace source —
 two jobs submitting the same zoo name with the same args share the
-process-global wave/drain executables (``checker/tpu.py``'s
+process-global wave/drain executables (``checker/programs.py``'s
 ``shared_aot_cache``), which is what makes a resident service cheap:
 same-shaped waves across tenants never recompile.
 """

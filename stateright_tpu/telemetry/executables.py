@@ -21,7 +21,6 @@ import contextlib
 import threading
 
 import jax
-from jax.api_util import shaped_abstractify
 
 _TRACE_EVENTS = (
     "/jax/core/compile/jaxpr_trace_duration",
@@ -97,38 +96,3 @@ def exe_span(tracer, name, kind, **args):
         yield sp
         found = acquired(before, build_meter())
         sp.set(**{k: v for k, v in found.items() if k not in sp.args})
-
-
-def aval_key(x) -> tuple:
-    """Shape, dtype and weak type of one argument leaf: what a compiled
-    executable is specialised to."""
-    a = shaped_abstractify(x)
-    return (a.shape, a.dtype, a.weak_type)
-
-
-class AotJit:
-    """A ``jax.jit`` run as ahead-of-time executables that ``obtain(kind,
-    key, jit_fn, args)`` supplies. The key holds the arguments at the
-    ``static_argnums`` positions (baked into the executable, so the call
-    drops them), the pytree structure of the others and each of their
-    leaves' ``aval_key``: callers whose ``obtain`` looks in one dict share
-    executables."""
-
-    __slots__ = ("_obtain", "_kind", "_jit", "_static")
-
-    def __init__(self, obtain, kind, fn, static_argnums=(), **jit_kwargs):
-        self._obtain = obtain
-        self._kind = kind
-        self._jit = jax.jit(fn, static_argnums=static_argnums, **jit_kwargs)
-        self._static = frozenset(static_argnums)
-
-    def __call__(self, *args):
-        static = self._static
-        if static:
-            fixed = tuple(a for i, a in enumerate(args) if i in static)
-            dyn = tuple(a for i, a in enumerate(args) if i not in static)
-        else:
-            fixed, dyn = (), args
-        leaves, tree = jax.tree_util.tree_flatten(dyn)
-        key = (fixed, tree, tuple(map(aval_key, leaves)))
-        return self._obtain(self._kind, key, self._jit, args)(*dyn)

@@ -194,7 +194,7 @@ def test_fps_checks_share_every_program():
     assert {"materialize", "compact", "wave", "init", "take"} <= kinds
     assert {e["args"]["kind"] for e in exes} == kinds
     assert {e["args"]["source"] for e in exes} == {"memory"}
-    assert second._prog_exec is first._prog_exec
+    assert second._programs.cache is first._programs.cache
     assert answers(first) == answers(second) == answers(host)
 
 
@@ -206,7 +206,7 @@ def test_signatures_under_one_namespace_do_not_share(two_checks):
     finally:
         get_tracer().remove_sink(rec, close=False)
     assert other.unique_state_count() == 1568
-    assert other._prog_exec is not two_checks[0][0]._prog_exec
+    assert other._programs.cache is not two_checks[0][0]._programs.cache
     exes = _spans(rec.events, "tpu_bfs.exe")
     assert exes and all(e["args"]["source"] != "memory" for e in exes)
 
@@ -221,8 +221,7 @@ def test_checker_without_aot_cache_shares_nothing(two_checks):
         get_tracer().remove_sink(rec, close=False)
     assert lone.unique_state_count() == 288
     shared = two_checks[0][0]
-    for name in ("_prog_exec", "_drain_exec", "_wave_exec"):
-        assert getattr(lone, name) is not getattr(shared, name), name
+    assert lone._programs.cache is not shared._programs.cache
     exes = _spans(rec.events, "tpu_bfs.exe")
     assert {"init", "drain"} <= {e["args"]["kind"] for e in exes}
     assert all(e["args"]["source"] != "memory" for e in exes)

@@ -369,8 +369,10 @@ def test_pack_fault_blast_radius_is_one_tenant(baseline):
     """4 packed tenants, one injected per-tenant verdict fault: the 3
     survivors complete with ZERO preemptions, the faulted tenant is
     lane-dropped with its rolled-back payload slice and its solo retry
-    matches the solo baseline bit-identically."""
-    svc = _service()
+    matches the solo baseline bit-identically. The quantum outlasts the
+    test's own timeout: a slice that ends while the faulted tenant's solo
+    retry waits preempts the pack for the schedule, not for the fault."""
+    svc = _service(quantum_s=300.0)
     try:
         with inject(
             FaultSpec("pack.tenant.verdict", tenant="blast-2", at=0)

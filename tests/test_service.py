@@ -77,12 +77,20 @@ def test_concurrent_jobs_preempt_and_stay_exact():
     tests/test_packed_tenancy.py.
 
     Contention does not hang on the machine's speed: every pass of a
-    job's drain loop is held 50 ms (an injected ``wave.stall``), and
-    a 2pc-4 job makes more passes than one quantum holds, so neither job
-    can finish inside its first slice whatever its set-up costs."""
+    job's drain loop is held 50 ms (an injected ``wave.stall``), and the
+    service's clock counts only those holds. A quantum is then 15 passes
+    of the running job, however long its respawn and restore take, and a
+    2pc-4 job makes more passes than that, so neither job can finish
+    inside its first slice and every slice makes progress."""
     stall_s = 0.05
+    inj = None
+
+    def clock():
+        return 0.0 if inj is None else inj.hits("wave.stall") * stall_s
+
     svc = CheckService(
-        quantum_s=0.75, default_spawn=dict(SPAWN_2PC), packing=False
+        quantum_s=0.75, default_spawn=dict(SPAWN_2PC), packing=False,
+        clock=clock,
     )
     try:
         with inject(
@@ -137,7 +145,7 @@ def test_second_job_shares_aot_cache_zero_compiles(service):
     """The acceptance criterion: two jobs of the same wave shape share
     the AOT rung cache — the later job's attribution ledger records
     ZERO compile phases (in-wave and outside-wave both)."""
-    from stateright_tpu.checker.tpu import clear_shared_aot_caches
+    from stateright_tpu.checker.programs import clear_shared_aot_caches
 
     # The cache is process-global (that's the point — it outlives
     # service instances); clear it so THIS test's first job provably

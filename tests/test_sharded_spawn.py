@@ -72,15 +72,16 @@ def test_shared_aot_cache_builds_nothing_new(spans):
                  table_capacity_per_device=64, aot_cache="test-shared")
     first = TwoPhaseSys(4).checker().spawn_tpu_bfs(**spawn).join()
     want = answers(first, TwoPhaseSys(4))
-    built = dict(first._exec)
+    built = dict(first._programs.cache)
     # The table grew past 64 rows: a drain per table size, both shared.
     assert sum(1 for k in built if k[0] == "drain") >= 2
     second = TwoPhaseSys(4).checker().spawn_tpu_bfs(**spawn).join()
     assert answers(second, TwoPhaseSys(4)) == want
-    assert second._exec is first._exec and second._exec == built
+    cache = second._programs.cache
+    assert cache is first._programs.cache and cache == built
     acquired = [e for e in spans.of(second) if e["name"] == "sharded_bfs.exe"]
     assert {e["args"]["kind"] for e in acquired} >= {"drain", "rehash"}
-    assert {e["args"]["source"] for e in acquired} == {"shared"}
+    assert {e["args"]["source"] for e in acquired} == {"memory"}
 
 
 def test_spans_of_one_check(spans):

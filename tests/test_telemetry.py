@@ -198,7 +198,8 @@ def test_traced_jit_records_only_calls_that_obtain_an_executable():
     executable came from."""
     import jax.numpy as jnp
 
-    from stateright_tpu.telemetry.executables import AotJit, exe_span
+    from stateright_tpu.checker.programs import AotJit
+    from stateright_tpu.telemetry.executables import exe_span
 
     tracer = Tracer()
     exes, keys = {}, []
